@@ -780,8 +780,7 @@ def _bench_autotune(eb, shapes, repeat, log, stream_shape=(8, 32, 32),
     from repro.core import compress_stream, compress_tiled
     from repro.data import synthetic
 
-    table = at.calibrate(backends=("xla", "numpy"), eb=eb, save=False,
-                         jit_cache=False)
+    table = at.calibrate(backends=("xla", "numpy"), eb=eb, save=False)
     model = at.CostModel(coeffs=table.coeffs, kind=table.device_kind)
     rows = []
     base = CompressionConfig(eb=eb, mode="rel", predictor="mop",

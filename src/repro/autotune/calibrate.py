@@ -15,9 +15,9 @@ version or another device kind is refused with a typed
 ``CalibrationTableError`` (reason "stale" / "foreign"), never silently
 used -- a TPU-fitted table would invert every CPU trade-off.
 
-Calibration runs enable JAX's persistent compilation cache
-(``perfflags.apply_jit_cache``) so repeated invocations stop paying
-cold jit; REPRO_JIT_CACHE overrides the location.
+Calibration runs share JAX's persistent compilation cache with every
+other run (``perfflags.configure_compile_cache``), so repeated
+invocations stop paying cold jit.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from typing import Optional
 
 import numpy as np
 
-from .. import obs, perfflags
+from .. import obs
 from . import costmodel
 
 TABLE_FORMAT = "repro-autotune-calib"
@@ -188,8 +188,8 @@ def _stage_elems(kind, stage, shape, grid):
 
 
 def calibrate(shapes=CALIB_SHAPES, backends=None, eb: float = 1e-2,
-              path: Optional[str] = None, save: bool = True,
-              jit_cache: bool = True) -> CalibrationTable:
+              path: Optional[str] = None,
+              save: bool = True) -> CalibrationTable:
     """Run the calibration workload and fit a CalibrationTable.
 
     ``backends`` defaults to every backend worth searching on this host
@@ -200,11 +200,6 @@ def calibrate(shapes=CALIB_SHAPES, backends=None, eb: float = 1e-2,
     from ..core import compressor, tiling
     from . import search as search_mod
 
-    if jit_cache:
-        perfflags.apply_jit_cache(
-            perfflags.jit_cache_dir()
-            or os.path.join(os.path.dirname(default_table_path()),
-                            "jax-cache"))
     backends = tuple(backends or search_mod.available_backends())
     kind = costmodel.device_kind()
     samples = {}

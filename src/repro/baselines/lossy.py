@@ -197,12 +197,12 @@ def cpsz_like(u, v, eb=1e-2, mode="rel", level=12, block=16, **kw):
 def _slice_only_eb(ufp, vfp, tau):
     """Per-vertex bound from time-slice faces only (cpSZ semantics)."""
     from ..core import grid, sos
-    from ..core.ebound import _faces_eb_update, _incidence_table
+    from ..core.ebound import _faces_eb_update, _incidence_rows, face_rows
 
     T, H, W = ufp.shape
     HW = H * W
-    slice_tab = jnp.asarray(grid.slab_faces(H, W)["slice0"])
-    slice_inc = jnp.asarray(_incidence_table(H, W, "slice"))
+    slice_tab = jnp.asarray(face_rows(grid.slab_faces(H, W)["slice0"]))
+    slice_inc = jnp.asarray(_incidence_rows(H, W, "slice"))
     u2 = ufp.reshape(T, HW)
     v2 = vfp.reshape(T, HW)
 

@@ -1,8 +1,9 @@
 """Kernel-dispatch backend for the compression hot path (DESIGN.md #4).
 
-The three hot ops of the pipeline -- fused dual-quantize + block-Lorenzo
-residual, semi-Lagrangian prediction, and the SoS face predicate -- are
-routed through one of three interchangeable backends:
+The four hot ops of the pipeline -- fused dual-quantize + block-Lorenzo
+residual, semi-Lagrangian prediction, the SoS face predicate and the
+device entropy stage's symbol histogram -- are routed through one of
+three backend names:
 
   ``pallas``  the Pallas TPU kernels under ``repro.kernels`` (compiled
               on TPU, ``interpret=True`` elsewhere) -- the production
@@ -10,18 +11,25 @@ routed through one of three interchangeable backends:
   ``xla``     the pure-jnp implementations in core (default off-TPU);
   ``numpy``   host reference implementations.
 
+A backend name does not bind every op to its own implementation:
+``BINDINGS`` is the one per-op table, and ``op_bindings`` adds the
+per-plan rules (Lorenzo block size, int32 headroom).  Every dispatch
+below follows the table; an op asked to run on a binding it cannot
+honour raises instead of quietly running something else.
+
 Determinism contract (DESIGN.md #4):
 
-* The two INTEGER ops (Lorenzo residual, SoS predicate) are exact and
-  bit-identical across all three backends; tests/test_backend_parity.py
-  enforces this on residual streams, lossless masks and blockmaps.
+* The INTEGER ops (Lorenzo residual, SoS predicate, histogram) are
+  exact and bit-identical across all three backends;
+  tests/test_backend_parity.py enforces this on residual streams,
+  lossless masks and blockmaps.
 * The SL predictor is float and float arithmetic is not bit-stable
   across different XLA compilation contexts, so encoder, verify loop
   and decoder all call the SAME per-frame executable returned by
   ``sl_stepper`` -- consistency is structural, not numerical.  The
-  blob header records which backend produced the SL predictions
-  (``sl_backend``) and decompress replays that stepper.  xla/numpy
-  steppers share f64 math; the pallas stepper is the f32 TPU kernel.
+  blob header records which stepper produced the SL predictions
+  (``sl_backend``: the op's binding, ``xla`` or ``numpy``) and
+  decompress replays it.  Both steppers share f64 math.
 
 Backend selection: explicit argument > ``REPRO_BACKEND`` env var
 (perfflags) > auto (``pallas`` on TPU, ``xla`` elsewhere).
@@ -38,10 +46,23 @@ from .. import perfflags
 from ..kernels.cptest import ops as _cp_ops
 from ..kernels.entropy import ops as _ent_ops
 from ..kernels.lorenzo import ops as _lz_ops
-from ..kernels.semilagrange import kernel as _sl_kernel
 from . import predictors, quantize, sos
 
 BACKENDS = ("pallas", "xla", "numpy")
+OPS = ("lorenzo", "semilagrange", "cptest", "entropy")
+
+# op -> implementation for each backend name.  On ``pallas`` the SL op
+# binds to the XLA stepper: its backtrace samples the previous frame at
+# arbitrary per-element (row, col) positions, and Mosaic lowers only
+# gathers shaped like take_along_axis along one axis (JAX 0.9.0 refuses
+# the 2D VMEM gathers of kernels/semilagrange for TPU v5e), so that
+# kernel cannot be compiled for the chip.
+BINDINGS = {
+    "pallas": {"lorenzo": "pallas", "semilagrange": "xla",
+               "cptest": "pallas", "entropy": "pallas"},
+    "xla": {op: "xla" for op in OPS},
+    "numpy": {op: "numpy" for op in OPS},
+}
 
 
 def resolve(name: str | None = None) -> str:
@@ -54,8 +75,21 @@ def resolve(name: str | None = None) -> str:
     return name
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def op_bindings(be: str, block: int = predictors.DEFAULT_BLOCK,
+                xi_unit: int = 4) -> dict:
+    """The op -> implementation table of one plan.
+
+    Beyond ``BINDINGS``, the pallas Lorenzo kernel binds only where it
+    can compute the op: its tile-local context is the fixed
+    ``LBLOCK`` (16) block, and it computes in int32, where at
+    xi_unit < 4 a worst-case residual (8 * 2^29 / xi_unit) could wrap.
+    Elsewhere the op binds to xla, and the plan says so.
+    """
+    table = dict(BINDINGS[be])
+    if table["lorenzo"] == "pallas" and (
+            block != _lz_ops.kernel.LBLOCK or xi_unit < 4):
+        table["lorenzo"] = "xla"
+    return table
 
 
 # ----------------------------------------------------------------------
@@ -99,10 +133,12 @@ def lorenzo_residual(dfp, k, lossless, xi_unit,
     boundaries -- while the pallas kernel re-fuses it from dfp by
     design (one HBM pass) and the numpy reference stays self-contained.
     """
-    if backend == "pallas" and block == _lz_ops.kernel.LBLOCK:
-        out = _lz_ops.dualquant_lorenzo_residual(
-            dfp, k, lossless, xi_unit, block, force_pallas=True
-        )
+    if backend == "pallas":
+        if block != _lz_ops.kernel.LBLOCK:
+            raise ValueError(
+                f"the pallas Lorenzo kernel computes {_lz_ops.kernel.LBLOCK}"
+                f"-blocks, not {block}; bind the op with op_bindings()")
+        out = _lz_ops.dualquant_lorenzo_residual(dfp, k, lossless, xi_unit)
         return out.astype(jnp.int64)
     if backend == "numpy":
         return _lorenzo_residual_np(dfp, k, lossless, xi_unit, block)
@@ -178,41 +214,26 @@ def _sl_predict_frame_np(xu_prev, xv_prev, g2f, cfl_x, cfl_y, d_max, n_max):
 
 
 @functools.lru_cache(maxsize=64)
-def sl_stepper(backend, cfl_x, cfl_y, d_max, n_max):
+def sl_stepper(binding, cfl_x, cfl_y, d_max, n_max):
     """The per-frame SL prediction executable F(xu_prev, xv_prev, g2f).
 
-    F maps frame t-1's base-grid integer planes to frame t's integer
-    predictions.  The SAME returned callable (one jitted executable per
-    (backend, CFL, d_max, n_max)) is used by the encoder's residual
-    pass, the verify loop's decode simulation, and decompress -- which
-    is what makes the float prediction consistent end-to-end (module
-    doc).  g2f stays a traced argument so eb sweeps don't recompile.
+    ``binding`` is the SL op's implementation (``xla`` or ``numpy``;
+    see ``BINDINGS``).  F maps frame t-1's base-grid integer planes to
+    frame t's integer predictions.  The SAME returned callable (one
+    jitted executable per (binding, CFL, d_max, n_max)) is used by the
+    encoder's residual pass, the verify loop's decode simulation, and
+    decompress -- which is what makes the float prediction consistent
+    end-to-end (module doc).  g2f stays a traced argument so eb sweeps
+    don't recompile.
     """
-    if backend == "numpy":
+    if binding == "numpy":
         def step_np(xu_prev, xv_prev, g2f):
             return _sl_predict_frame_np(
                 np.asarray(xu_prev), np.asarray(xv_prev), float(g2f),
                 cfl_x, cfl_y, d_max, n_max)
         return step_np
-
-    if backend == "pallas":
-        @jax.jit
-        def step_pallas(xu_prev, xv_prev, g2f):
-            H, W = xu_prev.shape
-            if H % _sl_kernel.TILE_H:  # kernel needs row-tile alignment
-                return predictors.sl_predict_frame(
-                    xu_prev, xv_prev, g2f, cfl_x, cfl_y, d_max, n_max,
-                    early_exit=True)
-            g2 = jnp.asarray(g2f, jnp.float32)
-            u = xu_prev.astype(jnp.float32) * g2
-            v = xv_prev.astype(jnp.float32) * g2
-            pu, pv = _sl_kernel.sl_predict_pallas(
-                u, v, float(cfl_x), float(cfl_y), float(d_max), int(n_max),
-                interpret=_interpret(),
-            )
-            return (jnp.rint(pu / g2).astype(jnp.int64),
-                    jnp.rint(pv / g2).astype(jnp.int64))
-        return step_pallas
+    if binding != "xla":
+        raise ValueError(f"no SL stepper is bound to {binding!r}")
 
     @jax.jit
     def step_xla(xu_prev, xv_prev, g2f):
@@ -344,23 +365,30 @@ def connected_labels(n: int, edges, backend="xla"):
 # ----------------------------------------------------------------------
 
 def face_crossed(fu, fv, fidx, backend="xla", n_verts=None):
-    """Exact SoS predicate on batched faces; fu/fv/fidx (..., 3).
+    """Exact SoS predicate on batched faces given slot-major: fu, fv,
+    fidx (3, ...) -- row s holds every face's slot-s vertex.
 
     ``n_verts`` (static total space-time vertex count) guards the pallas
     int32-limb kernel's id-width precondition.
     """
-    if backend == "pallas" and (n_verts is None or n_verts < 2**31):
-        shape = fu.shape[:-1]
+    if backend == "pallas":
+        if n_verts is not None and n_verts >= 2**31:
+            raise ValueError(
+                f"{n_verts} space-time vertices overflow the pallas "
+                "predicate kernel's int32 vertex ids; tile the field")
+        shape = fu.shape[1:]
         n = int(np.prod(shape)) if shape else 1
         out = _cp_ops.face_crossed_batch(
-            jnp.reshape(fu, (n, 3)), jnp.reshape(fv, (n, 3)),
-            jnp.reshape(fidx, (n, 3)),
+            jnp.reshape(fu, (3, n)), jnp.reshape(fv, (3, n)),
+            jnp.reshape(fidx, (3, n)),
         )
         return jnp.reshape(out, shape)
+    xp = jnp
     if backend == "numpy":
-        return sos.face_crossed_vals(np, np.asarray(fu), np.asarray(fv),
-                                     np.asarray(fidx))
-    return sos.face_crossed_vals(jnp, fu, fv, fidx)
+        xp = np
+        fu, fv, fidx = np.asarray(fu), np.asarray(fv), np.asarray(fidx)
+    return sos.face_crossed(xp, fu[0], fv[0], fidx[0], fu[1], fv[1],
+                            fidx[1], fu[2], fv[2], fidx[2])
 
 
 # ----------------------------------------------------------------------

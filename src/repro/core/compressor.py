@@ -35,6 +35,7 @@ compatibility (tests, baselines, benchmarks).
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Optional
 
@@ -48,8 +49,7 @@ from . import ebound, ebpolicy, encode, fixedpoint, pipeline, predictors, \
 from .ebpolicy import DegenerateRangeError, TilePolicy, UniformPolicy
 
 jax.config.update("jax_enable_x64", True)
-# opt-in persistent compilation cache (REPRO_JIT_CACHE; README)
-perfflags.apply_jit_cache()
+perfflags.configure_compile_cache()
 
 FORMAT_VERSION = pipeline.FORMAT_VERSION
 FORMAT_VERSION_ADAPTIVE = pipeline.FORMAT_VERSION_ADAPTIVE
@@ -221,9 +221,18 @@ def compress(u, v, cfg: Optional[CompressionConfig] = None,
     return pipeline.pack_field(ex, u, v, enc, t0)
 
 
-def decompress(blob: bytes, backend: Optional[str] = None):
+def decompress(blob, backend: Optional[str] = None):
+    """Decode a container given as bytes or as a path to its file (a
+    tiled container file is range-read, not loaded whole)."""
+    from . import tiling
+
+    if isinstance(blob, (str, os.PathLike)):
+        with open(blob, "rb") as f:
+            head = f.read(len(encode.MAGIC_TILED))
+            if encode.is_tiled(head):
+                return tiling.decompress_tiled(blob, backend=backend)
+            blob = head + f.read()
     if encode.is_tiled(blob):
-        from . import tiling
         return tiling.decompress_tiled(blob, backend=backend)
     header, sections = encode.unpack(blob)
     version = header.get("version", 1)

@@ -91,18 +91,23 @@ def face_rotation_ebs(xp, fu, fv, crossed):
     they are computed once and shared (bit-identical to the per-rotation
     evaluation: integer dets, identical float division operands).
     """
-    a_u, b_u, c_u = fu[..., 0], fu[..., 1], fu[..., 2]
-    a_v, b_v, c_v = fv[..., 0], fv[..., 1], fv[..., 2]
+    us = tuple(fu[..., s] for s in range(3))
+    vs = tuple(fv[..., s] for s in range(3))
+    a_u, b_u, c_u = us
+    a_v, b_v, c_v = vs
     d_ab = a_u * b_v - a_v * b_u
     d_bc = b_u * c_v - b_v * c_u
     d_ca = c_u * a_v - c_v * a_u
-    return _rotation_ebs_from_dets(
-        xp, fu, fv, crossed, d_ab, d_bc, d_ca)
+    return xp.stack(_rotation_ebs_from_dets(
+        xp, us, vs, crossed, d_ab, d_bc, d_ca), axis=-1)
 
 
-def _rotation_ebs_from_dets(xp, fu, fv, crossed, d_ab, d_bc, d_ca):
-    a_u, b_u, c_u = fu[..., 0], fu[..., 1], fu[..., 2]
-    a_v, b_v, c_v = fv[..., 0], fv[..., 1], fv[..., 2]
+def _rotation_ebs_from_dets(xp, us, vs, crossed, d_ab, d_bc, d_ca):
+    """Per-slot bounds (eb_a, eb_b, eb_c) from the slot components
+    ``us = (a_u, b_u, c_u)``, ``vs = (a_v, b_v, c_v)``; zero on crossed
+    faces."""
+    a_u, b_u, c_u = us
+    a_v, b_v, c_v = vs
     f = jnp.float64 if xp is jnp else np.float64
     m = d_ca + d_bc + d_ab
     absm = xp.abs(m).astype(f)
@@ -125,15 +130,14 @@ def _rotation_ebs_from_dets(xp, fu, fv, crossed, d_ab, d_bc, d_ca):
         eb = xp.where(same_u, xp.maximum(eb, (xp.abs(su) - 1).astype(f)), eb)
         eb = xp.where(same_v, xp.maximum(eb, (xp.abs(sv) - 1).astype(f)), eb)
         eb_int = xp.floor(eb * _MARGIN).astype(xp.int64) - 1
-        zero = (m == 0) | (den1 == 0) | (den2 == 0)
+        zero = (m == 0) | (den1 == 0) | (den2 == 0) | crossed
         eb_int = xp.where(zero, xp.zeros_like(eb_int), eb_int)
         return xp.maximum(eb_int, 0)
 
     eb_c = rot_eb(d_ca, d_bc, a_u, a_v, b_u, b_v, c_u, c_v)
     eb_a = rot_eb(d_ab, d_ca, b_u, b_v, c_u, c_v, a_u, a_v)
     eb_b = rot_eb(d_bc, d_ab, c_u, c_v, a_u, a_v, b_u, b_v)
-    ebs = xp.stack([eb_a, eb_b, eb_c], axis=-1)
-    return xp.where(crossed[..., None], xp.zeros_like(ebs), ebs)
+    return eb_a, eb_b, eb_c
 
 
 @lru_cache(maxsize=32)
@@ -164,36 +168,55 @@ def _incidence_table(H: int, W: int, kind: str) -> np.ndarray:
     return out
 
 
+@lru_cache(maxsize=32)
+def _incidence_rows(H: int, W: int, kind: str) -> np.ndarray:
+    """``_incidence_table`` transposed to (K, n_verts) rows of
+    slot-major flat indices (slot * F + f; sentinel 3F), the layout the
+    device reduction gathers with."""
+    inc = _incidence_table(H, W, kind)
+    F = (grid.slab_faces(H, W)["slice0"] if kind == "slice"
+         else slab_face_table(H, W)).shape[0]
+    rows = np.where(inc < 3 * F, (inc % 3) * F + inc // 3, 3 * F)
+    return np.ascontiguousarray(rows.T)
+
+
+def face_rows(tab: np.ndarray) -> np.ndarray:
+    """(F, 3) face table -> (3, F) slot rows (device layout)."""
+    return np.ascontiguousarray(np.asarray(tab).T)
+
+
 def _faces_eb_update(u_flat, v_flat, idx_base, faces, tau, n_verts, inc):
     """Per-face ebs gather-min'd into a fresh (n_verts,) array.
 
     u_flat/v_flat: (n_verts,) int64 values of the vertex planes involved;
     idx_base: scalar global id of local vertex 0 (for SoS indices);
-    faces: (F, 3) int32 static table; inc: (n_verts, K) incidence table
-    (_incidence_table).  The three pairwise determinants are shared
-    between the crossed test and all Alg. 2 rotations.
+    faces: (3, F) int32 slot rows (face_rows); inc: (K, n_verts)
+    incidence rows (_incidence_rows).  The three pairwise determinants
+    are shared between the crossed test and all Alg. 2 rotations.
+
+    Every array stays 1-D per slot: a minor dimension of 3 (or K)
+    makes the TPU compiler's time grow with the face count -- minutes
+    per 500 x 500 slab -- where 1-D gathers compile in about a second.
     """
-    fu = u_flat[faces]
-    fv = v_flat[faces]
-    fidx = faces.astype(jnp.int64) + idx_base
-    a_u, b_u, c_u = fu[..., 0], fu[..., 1], fu[..., 2]
-    a_v, b_v, c_v = fv[..., 0], fv[..., 1], fv[..., 2]
+    us = tuple(u_flat[faces[s]] for s in range(3))
+    vs = tuple(v_flat[faces[s]] for s in range(3))
+    ids = tuple(faces[s].astype(jnp.int64) + idx_base for s in range(3))
+    a_u, b_u, c_u = us
+    a_v, b_v, c_v = vs
     d_ab = a_u * b_v - a_v * b_u
     d_bc = b_u * c_v - b_v * c_u
     d_ca = c_u * a_v - c_v * a_u
     crossed = sos.face_crossed(
-        jnp,
-        fu[..., 0], fv[..., 0], fidx[..., 0],
-        fu[..., 1], fv[..., 1], fidx[..., 1],
-        fu[..., 2], fv[..., 2], fidx[..., 2],
+        jnp, a_u, a_v, ids[0], b_u, b_v, ids[1], c_u, c_v, ids[2],
         d_ab=d_ab, d_bc=d_bc, d_ca=d_ca,
     )
-    ebs = _rotation_ebs_from_dets(jnp, fu, fv, crossed, d_ab, d_bc, d_ca)
+    ebs = _rotation_ebs_from_dets(jnp, us, vs, crossed, d_ab, d_bc, d_ca)
     big = jnp.asarray([2**62], dtype=jnp.int64)
-    ebs_flat = jnp.concatenate([ebs.reshape(-1), big])
-    out = jnp.minimum(jnp.min(ebs_flat[inc], axis=1),
-                      jnp.asarray(tau, jnp.int64))
-    return out, crossed
+    ebs_flat = jnp.concatenate([*ebs, big])
+    out = ebs_flat[inc[0]]
+    for k in range(1, inc.shape[0]):
+        out = jnp.minimum(out, ebs_flat[inc[k]])
+    return jnp.minimum(out, jnp.asarray(tau, jnp.int64)), crossed
 
 
 def derive_vertex_eb(ufp, vfp, tau: int):
@@ -204,11 +227,10 @@ def derive_vertex_eb(ufp, vfp, tau: int):
     """
     T, H, W = ufp.shape
     HW = H * W
-    slice_tab = jnp.asarray(grid.slab_faces(H, W)["slice0"])
-    sf = grid.slab_faces(H, W)
-    slab_tab = jnp.asarray(np.concatenate([sf["side"], sf["internal"]], axis=0))
-    slice_inc = jnp.asarray(_incidence_table(H, W, "slice"))
-    slab_inc = jnp.asarray(_incidence_table(H, W, "slab"))
+    slice_tab = jnp.asarray(face_rows(grid.slab_faces(H, W)["slice0"]))
+    slab_tab = jnp.asarray(face_rows(slab_face_table(H, W)))
+    slice_inc = jnp.asarray(_incidence_rows(H, W, "slice"))
+    slab_inc = jnp.asarray(_incidence_rows(H, W, "slab"))
 
     u2 = ufp.reshape(T, HW)
     v2 = vfp.reshape(T, HW)
@@ -242,11 +264,11 @@ def derive_vertex_eb(ufp, vfp, tau: int):
         slab_scan, 0, (jnp.arange(T - 1, dtype=jnp.int64), pairs_u, pairs_v)
     )
 
-    eb = eb_slice
-    # slab [t, t+1] contributes its plane-0 bounds to time t ...
-    eb = eb.at[:-1].min(eb_slab2[:, 0])
-    # ... and its plane-1 bounds to time t+1.
-    eb = eb.at[1:].min(eb_slab2[:, 1])
+    # slab [t, t+1] contributes its plane-0 bounds to time t and its
+    # plane-1 bounds to time t+1 (shifted elementwise mins, no scatter)
+    none = jnp.full((1, HW), 2**62, jnp.int64)
+    eb = jnp.minimum(eb_slice, jnp.concatenate([eb_slab2[:, 0], none]))
+    eb = jnp.minimum(eb, jnp.concatenate([none, eb_slab2[:, 1]]))
     return eb.reshape(T, H, W), slice_crossed, slab_crossed
 
 
@@ -263,26 +285,31 @@ def all_face_predicates(ufp, vfp, be: str = "xla"):
     T, H, W = ufp.shape
     HW = H * W
     n_verts = T * HW
-    sf = grid.slab_faces(H, W)
-    slab_tab_np = np.concatenate([sf["side"], sf["internal"]], axis=0)
+    slice_rows = face_rows(grid.slab_faces(H, W)["slice0"])
+    slab_rows = face_rows(slab_face_table(H, W))
 
     if be == "numpy":
         u2 = np.asarray(ufp).reshape(T, HW)
         v2 = np.asarray(vfp).reshape(T, HW)
-        st = sf["slice0"].astype(np.int64)
-        idx = st[None] + (np.arange(T, dtype=np.int64) * HW)[:, None, None]
+        toff = (np.arange(T, dtype=np.int64) * HW)[None, :, None]
+        st = slice_rows.astype(np.int64)
+        idx = st[:, None, :] + toff                      # (3, T, Fs)
         slice_pred = _backend.face_crossed(
-            u2[:, st], v2[:, st], idx, backend=be, n_verts=n_verts)
-        bt = slab_tab_np.astype(np.int64)
+            np.stack([u2[:, r] for r in st]),
+            np.stack([v2[:, r] for r in st]), idx, backend=be,
+            n_verts=n_verts)
+        bt = slab_rows.astype(np.int64)
         pair_u = np.concatenate([u2[:-1], u2[1:]], axis=1)
         pair_v = np.concatenate([v2[:-1], v2[1:]], axis=1)
-        idx = bt[None] + (np.arange(T - 1, dtype=np.int64) * HW)[:, None, None]
+        idx = bt[:, None, :] + toff[:, :-1]              # (3, T-1, Fb)
         slab_pred = _backend.face_crossed(
-            pair_u[:, bt], pair_v[:, bt], idx, backend=be, n_verts=n_verts)
+            np.stack([pair_u[:, r] for r in bt]),
+            np.stack([pair_v[:, r] for r in bt]), idx, backend=be,
+            n_verts=n_verts)
         return slice_pred, slab_pred
 
-    slice_tab = jnp.asarray(sf["slice0"])
-    slab_tab = jnp.asarray(slab_tab_np)
+    slice_tab = jnp.asarray(slice_rows)
+    slab_tab = jnp.asarray(slab_rows)
     u2 = ufp.reshape(T, HW)
     v2 = vfp.reshape(T, HW)
 

@@ -150,7 +150,6 @@ class PipelinePlan:
     name: str
     predictor: str
     backend: str
-    backend_lorenzo: str
     block: int
     n_levels: int
     scale: float
@@ -177,11 +176,11 @@ class PipelinePlan:
     def g2f(self) -> float:
         return (2.0 * self.xi_unit) / self.scale
 
-
-def lorenzo_backend(be: str, xi_unit: int) -> str:
-    """The pallas Lorenzo kernel is int32; at xi_unit < 4 a worst-case
-    residual (8 * 2^29 / xi_unit) could wrap, so demote that op to xla."""
-    return "xla" if (be == "pallas" and xi_unit < 4) else be
+    @property
+    def op_bindings(self) -> dict:
+        """op -> implementation actually run (backend.op_bindings)."""
+        return backend_mod.op_bindings(self.backend, self.block,
+                                       self.xi_unit)
 
 
 def plan_from_cfg(cfg, be: str, scale: float, eb_abs: float,
@@ -199,7 +198,6 @@ def plan_from_cfg(cfg, be: str, scale: float, eb_abs: float,
         name=name,
         predictor=knobs["predictor"],
         backend=be,
-        backend_lorenzo=lorenzo_backend(be, xi_unit),
         block=knobs["block"],
         n_levels=knobs["n_levels"],
         scale=scale,
@@ -239,7 +237,6 @@ def plan_from_header(header: dict, backend: Optional[str] = None
         name=name,
         predictor=header.get("predictor", "mop"),
         backend=be,
-        backend_lorenzo=lorenzo_backend(be, xi_unit),
         block=int(header["block"]),
         n_levels=1,
         scale=float(header["scale"]),
@@ -363,6 +360,8 @@ def _check_pt_core(xu_d, xv_d, lossless, lossless_extra, u_raw, v_raw,
 def _screen_unsafe_core(shape, slice_tab, slab_tab, ufp, vfp, ur_fp, vr_fp):
     """Faces whose predicate COULD have flipped (sound screen).
 
+    ``slice_tab``/``slab_tab`` are (3, F) slot rows (ebound.face_rows).
+
     A face all of whose u-components (or all of whose v-components)
     keep one strict sign in BOTH the original and the reconstruction
     cannot be crossed in either (the convex hull stays off the
@@ -377,17 +376,20 @@ def _screen_unsafe_core(shape, slice_tab, slab_tab, ufp, vfp, ur_fp, vr_fp):
     for o, r in ((ufp, ur_fp), (vfp, vr_fp)):
         masks.append(((o > 0) & (r > 0)).reshape(T, HW))
         masks.append(((o < 0) & (r < 0)).reshape(T, HW))
+    masks = jnp.stack(masks, axis=1)                 # (T, 4, HW)
 
-    def face_all(m, tab):
-        return m[:, tab[:, 0]] & m[:, tab[:, 1]] & m[:, tab[:, 2]]
+    def unsafe(tab):
+        # per frame (lax.map): 1-D gathers keep the TPU compile time
+        # independent of T
+        def one(m4):
+            pu, nu, pv, nv = (m[tab[0]] & m[tab[1]] & m[tab[2]]
+                              for m in m4)
+            return ~(pu | nu | pv | nv)
+        return one
 
-    def unsafe(window):
-        pu, nu, pv, nv = (face_all(m, tab) for m, tab in window)
-        return ~(pu | nu | pv | nv)
-
-    unsafe_slice = unsafe([(m, slice_tab) for m in masks])
-    pair = [jnp.concatenate([m[:-1], m[1:]], axis=1) for m in masks]
-    unsafe_slab = unsafe([(m, slab_tab) for m in pair])
+    unsafe_slice = jax.lax.map(unsafe(slice_tab), masks)
+    pair = jnp.concatenate([masks[:-1], masks[1:]], axis=2)
+    unsafe_slab = jax.lax.map(unsafe(slab_tab), pair)
     return unsafe_slice, unsafe_slab
 
 
@@ -400,9 +402,9 @@ class UnitFns:
     (shape x block x n_levels x predictor x backend); registered once in
     the keyed ``unit_fns`` registry and shared by every path.
 
-    ``be_lorenzo`` routes only the Lorenzo-residual op: the pallas
-    kernel computes in int32 (|residual| <= 2^32 / xi_unit worst case),
-    so callers demote it to xla when xi_unit < 4 keeps no headroom.
+    ``be_lorenzo`` routes only the Lorenzo-residual op: the plan's
+    ``op_bindings`` bind it to xla where the pallas kernel cannot
+    compute it (block size, int32 headroom; backend.op_bindings).
     """
 
     def __init__(self, shape, block, n_levels, predictor, be,
@@ -416,8 +418,8 @@ class UnitFns:
         T, H, W = shape
         self.nb = (-(-H // block), -(-W // block))
         slice_tab, slab_tab = _face_tables(H, W)
-        self._slice_tab = jnp.asarray(slice_tab)
-        self._slab_tab = jnp.asarray(slab_tab)
+        self._slice_tab = jnp.asarray(ebound.face_rows(slice_tab))
+        self._slab_tab = jnp.asarray(ebound.face_rows(slab_tab))
         jit = (lambda f, **kw: f) if be == "numpy" else jax.jit
 
         self.lorenzo_stage = jit(self._lorenzo_stage)
@@ -501,13 +503,12 @@ class UnitFns:
                               u_raw, v_raw, scale, xi_unit, eb_abs)
 
     def _face_subset(self, ur_flat, vr_flat, verts):
-        """Predicates for an explicit face subset (incremental rounds)."""
+        """Predicates for an explicit face subset (incremental rounds);
+        ``verts`` (3, B) global vertex ids, slot-major."""
         T, H, W = self.shape
-        fu = ur_flat[verts]
-        fv = vr_flat[verts]
         return backend_mod.face_crossed(
-            fu, fv, verts.astype(jnp.int64), backend=self.be,
-            n_verts=T * H * W)
+            ur_flat[verts], vr_flat[verts], verts.astype(jnp.int64),
+            backend=self.be, n_verts=T * H * W)
 
 
 # explicit keyed registries (no LRU: shape churn can never evict a live
@@ -570,8 +571,8 @@ class _BatchStages:
 
         Te, he, we = ext_shape
         slice_tab, slab_tab = _face_tables(he, we)
-        slice_tab = jnp.asarray(slice_tab)
-        slab_tab = jnp.asarray(slab_tab)
+        slice_tab = jnp.asarray(ebound.face_rows(slice_tab))
+        slab_tab = jnp.asarray(ebound.face_rows(slab_tab))
         blk = block
 
         def _quant1(u, v, eb, extra, xi):
@@ -613,7 +614,7 @@ class _BatchStages:
                                        ufp, vfp, ur, vr)
 
         def mt(fn):
-            return jax.jit(lambda *b: sharding.map_tiles_padded(fn, *b))
+            return jax.jit(lambda *b: sharding.map_tiles(fn, *b))
 
         self.quant = mt(_quant1)
         self.res_lorenzo = mt(_res_lorenzo1)
@@ -862,7 +863,7 @@ def face_recheck(fns: UnitFns, shape, ur_fp, vr_fp, preds, selection):
     ], axis=0)
     crossed = np.asarray(fns.face_subset(
         ur_fp.reshape(-1), vr_fp.reshape(-1),
-        jnp.asarray(verts_p)))[: len(verts)]
+        jnp.asarray(np.ascontiguousarray(verts_p.T))))[: len(verts)]
     bad = crossed != orig
     if not bad.any():
         return None, 0
@@ -899,7 +900,8 @@ class PlanExecutor:
         self.plan = plan
         self._impl = dict(plan.bindings)
         self.stepper = backend_mod.sl_stepper(
-            plan.backend, plan.cfl_x, plan.cfl_y, plan.d_max, plan.n_max)
+            plan.op_bindings["semilagrange"], plan.cfl_x, plan.cfl_y,
+            plan.d_max, plan.n_max)
 
     @property
     def g2f(self):
@@ -908,7 +910,7 @@ class PlanExecutor:
     def fns(self, shape) -> UnitFns:
         p = self.plan
         return unit_fns(shape, p.block, p.n_levels, p.predictor,
-                        p.backend, p.backend_lorenzo)
+                        p.backend, p.op_bindings["lorenzo"])
 
     def batch_fns(self, sig) -> BatchFns:
         return batch_fns(sig, self.plan.block, self.plan.n_levels)
@@ -1315,7 +1317,7 @@ def field_header(plan: PipelinePlan, shape) -> dict:
     if plan.eb_policy:
         header["eb_policy"] = plan.eb_policy
     if plan.name != "legacy":
-        header["sl_backend"] = plan.backend
+        header["sl_backend"] = plan.op_bindings["semilagrange"]
     header.update({
         "shape": [int(T), int(H), int(W)],
         "scale": float(plan.scale),
@@ -1359,6 +1361,7 @@ def pack_field(ex: PlanExecutor, u, v, enc: FieldEncode, t0: float):
         "xi_unit": p.xi_unit,
         "seconds": t1 - t0,
         "backend": p.backend,
+        "bindings": p.op_bindings,
         "pipeline": p.name,
     }
     return blob, stats

@@ -65,8 +65,21 @@ def quantize_eb(eb, xi_unit, n_levels: int):
 
 
 def round_half_away_div(d, q):
-    """sign(d) * ((|d| + q//2) // q) for int64 d, even int64 q."""
-    mag = (jnp.abs(d) + (q >> 1)) // q
+    """sign(d) * ((|d| + q//2) // q) for int64 d, even int64 q > 0.
+
+    The quotient is taken in float64 and corrected one step either way
+    by the exact int64 remainder, which is exact while |d| < 2^51
+    (fixed-point values stay below 2^30): for |d| < q/2 the float
+    quotient is below 1 and the result 0, otherwise every operand is
+    below 2^52.  TPUs have no 64-bit integer divide, and XLA's emulated
+    one costs ~30 s of compile time per use at frame sizes; this form
+    compiles in about a second.
+    """
+    n = jnp.abs(d) + (q >> 1)
+    mag = jnp.floor(n.astype(jnp.float64) / q.astype(jnp.float64)
+                    ).astype(jnp.int64)
+    r = n - mag * q
+    mag = mag - (r < 0) + (r >= q)
     return jnp.sign(d) * mag
 
 

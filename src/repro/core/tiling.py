@@ -758,45 +758,57 @@ def _local_tet_faces(key):
 
 @functools.lru_cache(maxsize=64)
 def _batch_seg_fn(key, be: str):
-    """Batched crossed-face evaluator for one extension geometry.
+    """Batched crossed-face evaluator for one extension geometry:
+    ``run(us, vs)`` -> (B, nsl, 4, Ntl) bool, slab by slab.
 
     Local ids are order-isomorphic to global ids, so the SoS predicate
     is bit-identical to the global evaluation (the integer op contract:
     all backends agree, so jnp is used on-device and numpy on host).
-    The per-vertex gather indices and SoS id-order bools are pre-split
-    on the host (sos.face_crossed_ordered): embedding the combined
-    (N, 4, 3) int64 id table as a jit constant made XLA constant-fold
-    its slices and compares for >30 s per geometry at 128x128 tiles.
+    One slab's face table serves every slab of the unit -- slab s is
+    the table shifted by s planes, which changes no id order -- so the
+    per-vertex gather indices and SoS id-order bools (pre-split on the
+    host, sos.face_crossed_ordered) are (4, Ntl) rows, not an
+    (nsl * Ntl, 4, 3) jit constant: XLA constant-folded slices of that
+    for >30 s per geometry, and a minor dimension of 4 padded the TPU
+    gathers 32-fold past the chip's memory at 250 x 250 tiles.
     """
     fidx_np = _local_tet_faces(key)
     if fidx_np is None:
         return None
+    nsl = key[6]
+    P = key[1] * key[2]
+    slab0 = fidx_np[: len(fidx_np) // nsl]        # (Ntl, 4, 3), slab 0
     if be == "numpy":
         def run_np(us, vs):
-            return np.stack([
-                sos.face_crossed_vals(
-                    np, np.asarray(u).reshape(-1)[fidx_np],
-                    np.asarray(v).reshape(-1)[fidx_np], fidx_np)
-                for u, v in zip(us, vs)])
+            out = []
+            for u, v in zip(us, vs):
+                u, v = np.asarray(u).reshape(-1), np.asarray(v).reshape(-1)
+                out.append([sos.face_crossed_vals(
+                    np, u[slab0 + s * P], v[slab0 + s * P],
+                    slab0 + s * P).T for s in range(nsl)])
+            return np.asarray(out)
         return run_np
 
     from ..parallel import sharding
 
-    f0 = jnp.asarray(fidx_np[..., 0])
-    f1 = jnp.asarray(fidx_np[..., 1])
-    f2 = jnp.asarray(fidx_np[..., 2])
-    lt_ab = jnp.asarray(fidx_np[..., 0] < fidx_np[..., 1])
-    lt_bc = jnp.asarray(fidx_np[..., 1] < fidx_np[..., 2])
-    lt_ca = jnp.asarray(fidx_np[..., 2] < fidx_np[..., 0])
+    rows = slab0.transpose(2, 1, 0)                # (3, 4, Ntl)
+    f0, f1, f2 = (jnp.asarray(r.astype(np.int32)) for r in rows)
+    lt_ab = jnp.asarray(rows[0] < rows[1])
+    lt_bc = jnp.asarray(rows[1] < rows[2])
+    lt_ca = jnp.asarray(rows[2] < rows[0])
 
     def one(uu, vv):
         uf = uu.reshape(-1)
         vf = vv.reshape(-1)
-        return sos.face_crossed_ordered(
-            jnp, uf[f0], vf[f0], uf[f1], vf[f1], uf[f2], vf[f2],
-            lt_ab, lt_bc, lt_ca)
 
-    return jax.jit(lambda us, vs: sharding.map_tiles_padded(one, us, vs))
+        def slab(s):
+            o = s * P
+            return sos.face_crossed_ordered(
+                jnp, uf[f0 + o], vf[f0 + o], uf[f1 + o], vf[f1 + o],
+                uf[f2 + o], vf[f2 + o], lt_ab, lt_bc, lt_ca)
+        return jax.lax.map(slab, jnp.arange(nsl, dtype=jnp.int32))
+
+    return jax.jit(lambda us, vs: sharding.map_tiles(one, us, vs))
 
 
 def _unit_segment_records(st: _State, spec: TileSpec, crossed, key):
@@ -808,7 +820,8 @@ def _unit_segment_records(st: _State, spec: TileSpec, crossed, key):
     H, W = st.H, st.W
     ncc = nci * ncj
     Ntl = 6 * ncc
-    crossed = np.asarray(crossed).reshape(nsl * Ntl, 4)
+    # (nsl, 4, Ntl) slot-major -> (nsl * Ntl, 4)
+    crossed = np.asarray(crossed).transpose(0, 2, 1).reshape(nsl * Ntl, 4)
     from . import trajectory
     trajectory.check_lemma1(crossed.reshape(nsl, Ntl, 4), t_lo=spec.t0)
 
@@ -1065,7 +1078,7 @@ def _container_header(st: _State, T: int):
         "version": version,
         "pipeline": "tiled",
         "predictor": cfg.predictor,
-        "sl_backend": st.be,
+        "sl_backend": st.ex.plan.op_bindings["semilagrange"],
         "shape": [int(T), int(st.H), int(st.W)],
         "scale": float(st.scale),
         "xi_unit": int(st.xi_unit),
@@ -1104,6 +1117,7 @@ def _stats(st: _State, T, blob, t0):
         "xi_unit": st.xi_unit,
         "seconds": time.perf_counter() - t0,
         "backend": st.be,
+        "bindings": st.ex.plan.op_bindings,
         "pipeline": "tiled",
         "n_units": st.n_units,
         "tiling": dataclasses.asdict(st.grid),

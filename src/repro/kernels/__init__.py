@@ -2,10 +2,12 @@
 
 Each subpackage ships:
     kernel.py -- pl.pallas_call + BlockSpec VMEM tiling (TPU target)
-    ops.py    -- jit'd dispatch wrapper (TPU -> kernel, else ref)
+    ops.py    -- wrapper that pads to the tiling and runs the kernel
+                 compiled on TPU, in interpret mode elsewhere
     ref.py    -- pure-jnp oracle
 
 Kernels are validated in interpret mode on CPU (exact equality for the
-integer kernels); the dry-run model path never requires them (the
-framework is pure-JAX functional on any backend).
+integer kernels) and compiled for a described TPU v5e in
+tests/test_tpu_compile.py.  The semilagrange kernel does not compile
+for the TPU and is bound nowhere (core/backend.py BINDINGS).
 """

@@ -16,8 +16,10 @@ The SoS tie-break cascade (core/sos.py) runs on top of the exact signs.
 This is the TPU-native replacement for the paper's int64 CPU predicate
 -- the hardware-adaptation note in DESIGN.md #3.4/#7.
 
-Layout: faces are batched as (N, 128)-padded int32 planes; the grid
-walks (8, 128) VMEM tiles; pure VPU integer MACs, no MXU.
+Layout: faces arrive slot-major, as three (R, C) int32 planes per
+operand (a minor dimension of 3 would leave every vector tile 3/128
+full); the grid walks (8, 128) VMEM tiles; pure VPU integer MACs, no
+MXU.
 """
 from __future__ import annotations
 
@@ -70,7 +72,10 @@ def _sign_det_exact(au, av, bu, bv):
     rest = ((l3 << _B | l2) != 0) | ((l1 << _B | l0) != 0)
     pos = (l4 > 0) | ((l4 == 0) & rest)
     neg = l4 < 0
-    return jnp.where(pos, 1, jnp.where(neg, -1, 0)).astype(jnp.int32)
+    # int32 constants: with x64 on, bare Python ints would widen the
+    # select to int64, which Mosaic cannot lower
+    one = jnp.ones_like(l4)
+    return jnp.where(pos, one, jnp.where(neg, -one, jnp.zeros_like(l4)))
 
 
 def _sos_cascade(au, av, bu, bv):
@@ -79,7 +84,7 @@ def _sos_cascade(au, av, bu, bv):
     s = jnp.where(s != 0, s, jnp.sign(-bu))
     s = jnp.where(s != 0, s, jnp.sign(-av))
     s = jnp.where(s != 0, s, jnp.sign(au))
-    return jnp.where(s != 0, s, -jnp.ones_like(s)).astype(jnp.int32)
+    return jnp.where(s != 0, s, -jnp.ones_like(s))
 
 
 def _sign_det_sos(au, av, ma, bu, bv, mb):
@@ -100,16 +105,15 @@ def _kernel(u0, v0, u1, v1, u2, v2, m0, m1, m2, out):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def face_crossed_pallas(u, v, idx, interpret=True):
-    """u, v, idx: (R, C, 3) int32 (R % 8 == 0, C % 128 == 0).
+    """u, v, idx: (3, R, C) int32 slot planes (R % 8 == 0, C % 128 == 0).
 
     Returns (R, C) int32 (1 = crossed).
     """
-    R, C, _ = u.shape
+    _, R, C = u.shape
     grid = (R // TILE_R, C // TILE_C)
     tile = (TILE_R, TILE_C)
 
-    args = [u[..., 0], v[..., 0], u[..., 1], v[..., 1], u[..., 2], v[..., 2],
-            idx[..., 0], idx[..., 1], idx[..., 2]]
+    args = [u[0], v[0], u[1], v[1], u[2], v[2], idx[0], idx[1], idx[2]]
     return pl.pallas_call(
         _kernel,
         grid=grid,

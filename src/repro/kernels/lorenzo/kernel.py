@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LBLOCK = 16          # Lorenzo tile (matches core.predictors.DEFAULT_BLOCK)
 TILE_H = 128         # VMEM tile (8x sublane, 128-lane aligned)
@@ -41,15 +42,24 @@ def _dual_quant(dfp, k, lossless, g):
 
 
 def _d2_block(x):
-    """Tile-local 2D first-order difference (within-VMEM, no halo)."""
+    """Tile-local 2D first-order difference (within-VMEM, no halo).
+
+    The neighbours come from rotations: the wrapped-around row/column
+    lands only on block-boundary positions, which the masks zero.
+    Constants stay int32 (with x64 on, bare Python ints would widen to
+    int64, which Mosaic cannot lower).
+    """
     H, W = x.shape
     ii = jax.lax.broadcasted_iota(jnp.int32, (H, W), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (H, W), 1)
-    mi = ((ii % LBLOCK) != 0).astype(x.dtype)
-    mj = ((jj % LBLOCK) != 0).astype(x.dtype)
-    xi = jnp.pad(x, ((1, 0), (0, 0)))[:-1] * mi
-    xj = jnp.pad(x, ((0, 0), (1, 0)))[:, :-1] * mj
-    xij = jnp.pad(x, ((1, 0), (1, 0)))[:-1, :-1] * (mi * mj)
+    lb = jnp.int32(LBLOCK - 1)
+    mi = ((ii & lb) != 0).astype(x.dtype)
+    mj = ((jj & lb) != 0).astype(x.dtype)
+    one = jnp.int32(1)
+    x_up = pltpu.roll(x, one, 0)
+    xi = x_up * mi
+    xj = pltpu.roll(x, one, 1) * mj
+    xij = pltpu.roll(x_up, one, 1) * (mi * mj)
     return x - xi - xj + xij
 
 
@@ -81,6 +91,9 @@ def dualquant_lorenzo_residual_pallas(dfp, k, lossless, xi_unit,
     def idx_p(t, i, j):
         return (jnp.maximum(t - 1, 0), i, j)
 
+    def idx_meta(t, i, j):
+        return (jnp.int32(0),)   # int32: x64 would make a bare 0 int64
+
     tile = (1, TILE_H, TILE_W)
     in_specs = [
         pl.BlockSpec(tile, idx_t),                     # dfp_t
@@ -89,7 +102,8 @@ def dualquant_lorenzo_residual_pallas(dfp, k, lossless, xi_unit,
         pl.BlockSpec(tile, idx_p),                     # k_{t-1}
         pl.BlockSpec(tile, idx_t),                     # lossless_t
         pl.BlockSpec(tile, idx_p),                     # lossless_{t-1}
-        pl.BlockSpec(memory_space=pl.ANY),             # meta (scalars)
+        pl.BlockSpec((1,), idx_meta,                   # meta: 2*xi_unit
+                     memory_space=pltpu.SMEM),
     ]
     meta = (2 * jnp.asarray(xi_unit, dtype=jnp.int32)).reshape(1)
     return pl.pallas_call(

@@ -1,10 +1,11 @@
-"""Dispatch wrapper: TPU -> pallas kernel, CPU/other -> interpret/ref."""
+"""Wrapper: pad to the VMEM tile and run the kernel (compiled on TPU,
+interpret mode elsewhere)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from . import kernel, ref
+from . import kernel
 
 
 def _pad_to(x, mh, mw, value=0):
@@ -16,34 +17,18 @@ def _pad_to(x, mh, mw, value=0):
     return x
 
 
-def dualquant_lorenzo_residual(dfp, k, lossless, xi_unit, block=16,
-                               force_ref=False, force_pallas=False):
-    """Fused dual-quantization + block-local Lorenzo residual.
+def dualquant_lorenzo_residual(dfp, k, lossless, xi_unit):
+    """Fused dual-quantization + block-local (LBLOCK) Lorenzo residual.
 
     dfp int32/int64 (T, H, W); k int32 (-1 where lossless); lossless
-    bool.  Returns int32 residual (T, H, W).  ``force_pallas`` (used by
-    the core backend dispatcher) skips the large-field CPU heuristic so
-    the kernel always runs (interpret mode off-TPU).
+    bool.  Returns int32 residual (T, H, W); core.quantize +
+    core.predictors are the oracle.
     """
     T, H, W = dfp.shape
-    on_tpu = jax.default_backend() == "tpu"
-    if force_ref or (not force_pallas and not on_tpu and (H * W > 512 * 512)):
-        # pure-jnp path (identical math, vectorized)
-        x_prev = jnp.zeros((H, W), jnp.int32)
-        outs = []
-        for t in range(T):  # small T in ref mode; core pipeline is used
-            r = ref.residual_frame_pair(
-                dfp[t].astype(jnp.int32), dfp[max(t - 1, 0)].astype(jnp.int32),
-                k[t], k[max(t - 1, 0)], lossless[t], lossless[max(t - 1, 0)],
-                xi_unit, t == 0, block,
-            )
-            outs.append(r)
-        return jnp.stack(outs)
-
     dfp32 = _pad_to(dfp.astype(jnp.int32), kernel.TILE_H, kernel.TILE_W)
     k32 = _pad_to(k.astype(jnp.int32), kernel.TILE_H, kernel.TILE_W)
     ll = _pad_to(lossless, kernel.TILE_H, kernel.TILE_W)
     out = kernel.dualquant_lorenzo_residual_pallas(
-        dfp32, k32, ll, xi_unit, interpret=not on_tpu
+        dfp32, k32, ll, xi_unit, interpret=jax.default_backend() != "tpu"
     )
     return out[:, :H, :W]

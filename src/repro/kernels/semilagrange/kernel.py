@@ -1,15 +1,19 @@
-"""Pallas TPU kernel: semi-Lagrangian backtrace + bilinear sampling.
+"""Pallas kernel: semi-Lagrangian backtrace + bilinear sampling.
 
 The previous frame's (u, v) planes are held whole in VMEM (two
-f32[H, W] buffers -- up to ~2 x 4 MB for 1k x 1k frames, well within
-the 16 MB/core budget); the grid tiles the *output* rows, so the
-irregular reads of the backtrace stay on-chip and each output element is
-written once.  RK2 midpoint for small displacements, clamped Euler
-substeps otherwise (paper Eqs. 4-9), f32 arithmetic.
+f32[H, W] buffers); the grid tiles the *output* rows, so the irregular
+reads of the backtrace stay on-chip and each output element is written
+once.  RK2 midpoint for small displacements, clamped Euler substeps
+otherwise (paper Eqs. 4-9), f32 arithmetic.  The wrappers pad H to the
+row tile; the kernel clamps every read to the true H.
 
-Gather note: per-element VMEM gathers lower on TPU only for recent
-generations; the ops wrapper validates in interpret mode and keeps the
-pure-jnp path (XLA gather) as the production fallback.
+Not on the compression path, and not compilable for the TPU: the
+bilinear taps ``f[i0, j0]`` are per-element 2D gathers from VMEM, and
+Mosaic lowers only take_along_axis-shaped gathers along one axis
+(JAX 0.9.0 refuses this kernel for TPU v5e with "Unsupported gather").
+The SL op therefore binds to the XLA stepper on every backend
+(core/backend.py BINDINGS).  The kernel runs in interpret mode only,
+where tests pin it against the f32 oracle.
 """
 from __future__ import annotations
 
@@ -39,17 +43,14 @@ def _bilinear(f, fi, fj, H, W):
             + a * (1 - b) * f10 + a * b * f11)
 
 
-def _sl_tile(u, v, r, H, W, cfl_x, cfl_y, d_max, n_max):
-    """Backtrace + sample one (TILE_H, W) output row tile of one frame."""
+def _sl_tile(u, v, u0, v0, r, H, W, cfl_x, cfl_y, d_max, n_max):
+    """Backtrace + sample one (TILE_H, W) output row tile of one frame;
+    u0/v0 are the tile's own rows of u/v."""
     ii = (r * TILE_H
           + jax.lax.broadcasted_iota(jnp.int32, (TILE_H, W), 0)
           ).astype(jnp.float32)
     jj = jax.lax.broadcasted_iota(jnp.int32, (TILE_H, W), 1).astype(
         jnp.float32)
-    zero = jnp.zeros((), jnp.int32)
-    start = (r * TILE_H).astype(jnp.int32)
-    u0 = jax.lax.dynamic_slice(u, (start, zero), (TILE_H, W))
-    v0 = jax.lax.dynamic_slice(v, (start, zero), (TILE_H, W))
     d_inf = jnp.maximum(jnp.abs(u0) * cfl_x, jnp.abs(v0) * cfl_y)
 
     # RK2 midpoint
@@ -78,10 +79,22 @@ def _sl_tile(u, v, r, H, W, cfl_x, cfl_y, d_max, n_max):
     return _bilinear(u, i_s, j_s, H, W), _bilinear(v, i_s, j_s, H, W)
 
 
+def _pad_rows(x):
+    """Zero-pad the row axis (-2) to a multiple of TILE_H."""
+    ph = (-x.shape[-2]) % TILE_H
+    if not ph:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[-2] = (0, ph)
+    return jnp.pad(x, pad)
+
+
 def _make_kernel(H, W, cfl_x, cfl_y, d_max, n_max):
     def kernel(u_ref, v_ref, pu_ref, pv_ref):
         r = pl.program_id(0)
-        pu, pv = _sl_tile(u_ref[...], v_ref[...], r, H, W,
+        rows = pl.ds(pl.multiple_of(r * TILE_H, TILE_H), TILE_H)
+        pu, pv = _sl_tile(u_ref[...], v_ref[...], u_ref[rows, :],
+                          v_ref[rows, :], r, H, W,
                           cfl_x, cfl_y, d_max, n_max)
         pu_ref[...] = pu
         pv_ref[...] = pv
@@ -92,7 +105,9 @@ def _make_kernel(H, W, cfl_x, cfl_y, d_max, n_max):
 def _make_batched_kernel(H, W, cfl_x, cfl_y, d_max, n_max):
     def kernel(u_ref, v_ref, pu_ref, pv_ref):
         r = pl.program_id(1)
-        pu, pv = _sl_tile(u_ref[0], v_ref[0], r, H, W,
+        rows = pl.ds(pl.multiple_of(r * TILE_H, TILE_H), TILE_H)
+        pu, pv = _sl_tile(u_ref[0], v_ref[0], u_ref[0, rows, :],
+                          v_ref[0, rows, :], r, H, W,
                           cfl_x, cfl_y, d_max, n_max)
         pu_ref[0] = pu
         pv_ref[0] = pv
@@ -105,21 +120,23 @@ def _make_batched_kernel(H, W, cfl_x, cfl_y, d_max, n_max):
 )
 def sl_predict_pallas(u_prev, v_prev, cfl_x, cfl_y, d_max=2.0, n_max=8,
                       interpret=True):
-    """u_prev, v_prev: f32 (H, W), H % TILE_H == 0."""
+    """u_prev, v_prev: f32 (H, W)."""
     H, W = u_prev.shape
+    Hp = H + (-H) % TILE_H
     kern = _make_kernel(H, W, float(cfl_x), float(cfl_y), float(d_max),
                         int(n_max))
-    full = pl.BlockSpec((H, W), lambda r: (0, 0))
+    full = pl.BlockSpec((Hp, W), lambda r: (0, 0))
     tile = pl.BlockSpec((TILE_H, W), lambda r: (r, 0))
     pu, pv = pl.pallas_call(
         kern,
-        grid=(H // TILE_H,),
+        grid=(Hp // TILE_H,),
         in_specs=[full, full],
         out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((H, W), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((Hp, W), jnp.float32)] * 2,
         interpret=interpret,
-    )(u_prev.astype(jnp.float32), v_prev.astype(jnp.float32))
-    return pu, pv
+    )(_pad_rows(u_prev.astype(jnp.float32)),
+      _pad_rows(v_prev.astype(jnp.float32)))
+    return pu[:H], pv[:H]
 
 
 @functools.partial(
@@ -128,27 +145,23 @@ def sl_predict_pallas(u_prev, v_prev, cfl_x, cfl_y, d_max=2.0, n_max=8,
 def sl_predict_batched_pallas(u_prev, v_prev, cfl_x, cfl_y, d_max=2.0,
                               n_max=8, interpret=True):
     """Frame-batched variant: u_prev, v_prev f32 (B, H, W) stacks of
-    previous frames, H % TILE_H == 0.  One pallas_call over a (B, rows)
-    grid; each program holds its frame's two planes whole in VMEM and
-    writes one output row tile (same math as sl_predict_pallas).
-
-    NOT in the production hot path yet: the pipeline replays SL through
-    one per-frame stepper executable for encoder/decoder bit-consistency
-    (core/backend.py sl_stepper, DESIGN.md #4).  This kernel is the
-    TPU-compiled encoder upgrade once batched-vs-per-frame bitwise
-    equality is validated on hardware; tests pin it against the
-    per-frame kernel at f32 tolerance meanwhile."""
+    previous frames.  One pallas_call over a (B, rows) grid; each
+    program holds its frame's two planes whole in VMEM and writes one
+    output row tile (same math as sl_predict_pallas); tests pin it
+    against the per-frame kernel at f32 tolerance."""
     B, H, W = u_prev.shape
+    Hp = H + (-H) % TILE_H
     kern = _make_batched_kernel(H, W, float(cfl_x), float(cfl_y),
                                 float(d_max), int(n_max))
-    full = pl.BlockSpec((1, H, W), lambda b, r: (b, 0, 0))
+    full = pl.BlockSpec((1, Hp, W), lambda b, r: (b, 0, 0))
     tile = pl.BlockSpec((1, TILE_H, W), lambda b, r: (b, r, 0))
     pu, pv = pl.pallas_call(
         kern,
-        grid=(B, H // TILE_H),
+        grid=(B, Hp // TILE_H),
         in_specs=[full, full],
         out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((B, H, W), jnp.float32)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((B, Hp, W), jnp.float32)] * 2,
         interpret=interpret,
-    )(u_prev.astype(jnp.float32), v_prev.astype(jnp.float32))
-    return pu, pv
+    )(_pad_rows(u_prev.astype(jnp.float32)),
+      _pad_rows(v_prev.astype(jnp.float32)))
+    return pu[:, :H], pv[:, :H]

@@ -226,15 +226,7 @@ def param_shardings(params_shape, mesh: Mesh):
 # eb + seam-agreed verify), so the mapping needs no collectives --
 # in_specs == out_specs == P("tiles").  Every batched pipeline stage
 # (eb derivation, quantize, residuals, decode cumsum, pointwise check,
-# sign screen, segment extraction) routes through map_tiles*.
-
-
-def _shard_map_fn():
-    try:  # moved between jax versions
-        from jax.experimental.shard_map import shard_map
-        return shard_map
-    except ImportError:
-        return getattr(jax, "shard_map", None)
+# sign screen, segment extraction) routes through map_tiles.
 
 
 @functools.lru_cache(maxsize=1)
@@ -243,28 +235,36 @@ def tiles_mesh() -> Mesh:
 
     Cached: the batched pipeline stages re-enter map_tiles at every jit
     trace, and mesh construction is not free."""
-    return jax.make_mesh((jax.device_count(),), ("tiles",))
+    # Auto axis: the padded rows are sliced off the sharded output,
+    # which an Explicit (sharding-in-types) axis refuses
+    return jax.make_mesh((jax.device_count(),), ("tiles",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def map_tiles(fn, *batched):
-    """Apply ``fn`` (one tile unit -> pytree) over a leading tile axis.
+    """Apply ``fn`` (one tile unit -> pytree) over a leading tile axis
+    as shard_map(vmap(fn)) over the "tiles" mesh.
 
-    Uses shard_map(vmap(fn)) over the "tiles" mesh when the batch size
-    divides the local device count (it always does on one device, so CI
-    exercises the sharded path); plain vmap otherwise (the ragged
-    remainder still runs, just not device-parallel).
+    A batch whose size does not divide the device count is PADDED up to
+    a multiple (repeating the last tile) and the padded rows are dropped
+    from every output leaf, so every batch splits across every device;
+    ``fn`` must be row-independent (tile units are, by construction).
+    On one device nothing is padded.
     """
     import jax.numpy as jnp
 
     batched = [jnp.asarray(b) for b in batched]
     n = int(batched[0].shape[0])
-    vfn = jax.vmap(fn)
-    shard_map = _shard_map_fn()
-    if n and shard_map is not None and n % jax.device_count() == 0:
-        spec = P("tiles")
-        return shard_map(vfn, mesh=tiles_mesh(),
-                         in_specs=spec, out_specs=spec)(*batched)
-    return vfn(*batched)
+    pad = -n % jax.device_count()
+    if pad:
+        batched = [jnp.concatenate([b, jnp.repeat(b[-1:], pad, axis=0)],
+                                   axis=0) for b in batched]
+    spec = P("tiles")
+    out = jax.shard_map(jax.vmap(fn), mesh=tiles_mesh(),
+                        in_specs=spec, out_specs=spec)(*batched)
+    if pad:
+        out = jax.tree.map(lambda leaf: leaf[:n], out)
+    return out
 
 
 # --------------------------------------------------------- host workers
@@ -312,27 +312,3 @@ def host_map(pool, fn, items):
     if first_exc is not None:
         raise first_exc
     return results
-
-
-def map_tiles_padded(fn, *batched):
-    """map_tiles that PADS a ragged batch up to a device-count multiple
-    (repeating the last tile) so the shard_mapped path is always taken,
-    then drops the padded rows from every output leaf.
-
-    Used by the per-tile trajectory-segment extraction (core/tiling.py),
-    whose group sizes (edge/corner tile counts) rarely divide the device
-    count; ``fn`` must be row-independent (tile units are, by
-    construction).  On one device this degenerates to map_tiles.
-    """
-    import jax.numpy as jnp
-
-    batched = [jnp.asarray(b) for b in batched]
-    n = int(batched[0].shape[0])
-    d = jax.device_count()
-    if n == 0 or n % d == 0:
-        return map_tiles(fn, *batched)
-    pad = d - n % d
-    padded = [jnp.concatenate([b, jnp.repeat(b[-1:], pad, axis=0)], axis=0)
-              for b in batched]
-    out = map_tiles(fn, *padded)
-    return jax.tree.map(lambda leaf: leaf[:n], out)

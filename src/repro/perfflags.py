@@ -33,51 +33,31 @@ def fused_default():
     return os.environ.get("REPRO_FUSED", "1") != "0"
 
 
-def jit_cache_dir():
-    """REPRO_JIT_CACHE=<dir> points JAX's persistent compilation cache
-    at <dir>; REPRO_JIT_CACHE=1 uses ~/.cache/repro/jax-cache.  Unset
-    (or 0) disables it.  Read at call time so tests can monkeypatch."""
-    v = os.environ.get("REPRO_JIT_CACHE", "").strip()
-    if not v or v == "0":
-        return None
-    if v == "1":
-        return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                            "jax-cache")
-    return v
+# the checkout root: src/repro/perfflags.py -> <root>
+_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+JAX_CACHE_DIR = os.path.join(_ROOT, ".jax_cache")
 
 
-_jit_cache_applied = None
+def configure_compile_cache():
+    """Place JAX's persistent compilation cache.
 
-
-def apply_jit_cache(path=None):
-    """Idempotently enable JAX's persistent compilation cache at
-    ``path`` (default: ``jit_cache_dir()``; no-op when that is unset).
-
-    Repeated autotune/bench invocations re-jit the same stage
-    executables from scratch in every process; the on-disk cache turns
-    those cold compiles into loads.  Returns the applied path or None.
-    Purely a compile-time cache: numerics and container bytes are
-    unaffected.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at one fixed path
+    inside the checkout (``<checkout>/.jax_cache``, git-ignored): the
+    path is part of what the cache matches on, so it must not move
+    between runs.  Returns the directory in use (None outside a source
+    checkout, where the cache stays off).
     """
-    global _jit_cache_applied
-    path = path or jit_cache_dir()
-    if not path:
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if not os.path.exists(os.path.join(_ROOT, "pyproject.toml")):
         return None
-    if _jit_cache_applied == path:
-        return path
     import jax
 
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every entry: the compression stages are many small
-        # executables, each below the default min-compile-time bar
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        return None  # older jaxlibs without these flags
-    _jit_cache_applied = path
-    return path
+    jax.config.update("jax_compilation_cache_dir", JAX_CACHE_DIR)
+    return JAX_CACHE_DIR
 
 
 def checkpoint_if_optimized(fn):

@@ -28,8 +28,7 @@ def main():
         # calibrate on the numpy backend only (the leg has no
         # accelerator; xla would only add compile time to the smoke)
         path = os.path.join(td, "calib.json")
-        table = autotune.calibrate(backends=("numpy",), path=path,
-                                   jit_cache=False)
+        table = autotune.calibrate(backends=("numpy",), path=path)
         if not table.coeffs:
             raise SystemExit("calibration fitted no coefficients")
         reloaded = autotune.load_table(path)

@@ -30,7 +30,7 @@ def table(tmp_path_factory):
     noise, runs on any host)."""
     path = str(tmp_path_factory.mktemp("calib") / "table.json")
     return autotune.calibrate(shapes=SHAPES, backends=("numpy",),
-                              path=path, jit_cache=False)
+                              path=path)
 
 
 def _synthetic_table():

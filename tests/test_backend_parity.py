@@ -44,9 +44,9 @@ def test_face_crossed_op_parity(n):
     v = rng.integers(-(2**29), 2**29, (n, 3)).astype(np.int64)
     u[:: max(n // 5, 1)] = 0   # degeneracies
     idx = np.arange(3 * n, dtype=np.int64).reshape(n, 3)
-    outs = {
+    outs = {   # slot-major (3, n) operands
         be: np.asarray(backend_mod.face_crossed(
-            jnp.asarray(u), jnp.asarray(v), jnp.asarray(idx),
+            jnp.asarray(u.T), jnp.asarray(v.T), jnp.asarray(idx.T),
             backend=be, n_verts=3 * n))
         for be in BACKENDS
     }
@@ -211,7 +211,7 @@ def test_incremental_face_check_matches_full():
     assert len(verts)
     crossed = np.asarray(fns.face_subset(
         jnp.asarray(ufp.reshape(-1)), jnp.asarray(vfp.reshape(-1)),
-        jnp.asarray(verts)))
+        jnp.asarray(verts.T)))
     want = np.concatenate([np.asarray(full_slice)[ts, fs],
                            np.asarray(full_slab)[tb, fb]])
     assert (crossed == want).all()

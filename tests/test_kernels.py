@@ -84,7 +84,7 @@ def test_cptest_kernel_matches_sos(n):
     idx = np.arange(3 * n).reshape(n, 3)
     want = np.asarray(cp_ref.face_crossed(
         jnp.asarray(u), jnp.asarray(v), jnp.asarray(idx)))
-    got = np.asarray(cp_ops.face_crossed_batch(u, v, idx))
+    got = np.asarray(cp_ops.face_crossed_batch(u.T, v.T, idx.T))
     assert (got == want).all()
 
 
@@ -99,7 +99,7 @@ def test_cptest_small_values_near_zero():
     idx = np.arange(3 * n).reshape(n, 3)
     want = np.asarray(cp_ref.face_crossed(
         jnp.asarray(u), jnp.asarray(v), jnp.asarray(idx)))
-    got = np.asarray(cp_ops.face_crossed_batch(u, v, idx))
+    got = np.asarray(cp_ops.face_crossed_batch(u.T, v.T, idx.T))
     assert (got == want).all()
 
 
