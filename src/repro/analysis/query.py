@@ -23,8 +23,11 @@ would produce, node for node, bit for bit.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import hashlib
+import itertools
 import os
 import threading
 import time
@@ -232,13 +235,11 @@ class UnitCache:
         self._d: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
         self.cur_bytes = 0
-        # hit/miss/eviction accounting lives in obs child counters (the
-        # process totals appear in obs.snapshot() as cache.hits /
-        # cache.misses / cache.evicted_bytes); the public fields below
-        # are views over them
+        # hit/miss accounting lives in obs child counters (the process
+        # totals appear in obs.snapshot() as cache.hits / cache.misses);
+        # the public fields below are views over them
         self._c_hits = obs.child_counter("cache.hits")
         self._c_misses = obs.child_counter("cache.misses")
-        self._c_evicted = obs.child_counter("cache.evicted_bytes")
 
     @property
     def hits(self) -> int:
@@ -271,10 +272,7 @@ class UnitCache:
             self.cur_bytes += cost
             while self.cur_bytes > self.max_bytes:
                 _, (_, u_old, v_old) = self._d.popitem(last=False)
-                dropped = int(u_old.nbytes + v_old.nbytes)
-                self.cur_bytes -= dropped
-                self._c_evicted.add(dropped)
-        obs.gauge_set("cache.bytes", self.cur_bytes)
+                self.cur_bytes -= int(u_old.nbytes + v_old.nbytes)
 
     def clear(self):
         with self._lock:
@@ -282,7 +280,6 @@ class UnitCache:
             self.cur_bytes = 0
             self._c_hits.set_local(0)
             self._c_misses.set_local(0)
-        obs.gauge_set("cache.bytes", 0)
 
     def stats(self) -> dict:
         with self._lock:
@@ -305,6 +302,9 @@ def _cache_mb_from_env() -> float:
 
 unit_cache = UnitCache(int(_cache_mb_from_env() * 2**20))
 
+# ids of decode_for_track calls, for their spans
+_QIDS = itertools.count(1)
+
 
 def configure_unit_cache(max_mb: float) -> UnitCache:
     """Resize (and clear) the process-wide decoded-unit cache.
@@ -313,6 +313,43 @@ def configure_unit_cache(max_mb: float) -> UnitCache:
     unit_cache.clear()
     unit_cache.max_bytes = int(max_mb * 2**20)
     return unit_cache
+
+
+class _Decoding:
+    """The units that threads are decoding at this moment, as
+    ``(container_id, off)`` -> number of threads.  A cache miss on a
+    unit that another thread holds here is a duplicate decode
+    (``query.decode_dup``).  It only observes: both decodes still run.
+    Entered only while tracing is on."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._n = collections.Counter()
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._n)
+
+    def enter(self, keys: list) -> tuple:
+        """Hold ``keys``: (the set held, how many of them another thread
+        already holds)."""
+        with self._lock:
+            dup = sum(1 for k in keys if self._n[k])
+            self._n.update(keys)
+        return set(keys), dup
+
+    def leave(self, held: set, keys=None):
+        """Let go of ``keys`` (default: everything still ``held``)."""
+        keys = set(held) if keys is None else held & set(keys)
+        with self._lock:
+            for k in keys:
+                held.discard(k)
+                self._n[k] -= 1
+                if self._n[k] <= 0:
+                    del self._n[k]
+
+
+_decoding = _Decoding()
 
 
 def fetch_decoded_units(source: ContainerSource, ex, entries: list,
@@ -327,34 +364,48 @@ def fetch_decoded_units(source: ContainerSource, ex, entries: list,
     read, the CRC check, or decode are appended as ``(entry, exc)`` and
     SKIPPED -- the patch list then holds only the surviving units, in
     entry order.  Without it, the first damaged unit raises."""
-    cid = source.container_id
-    out = {}
-    missing = []
-    for e in entries:
-        got = unit_cache.get((cid, e["off"]))
-        if got is None:
-            missing.append(e)
-        else:
-            out[e["off"]] = got
-    n_hits = len(entries) - len(missing)
-    if missing:
-        obs.count("query.units_decoded", len(missing))
-        frames = source.read_many(missing, failures=failures)
-        for e, frame in zip(missing, frames):
-            if frame is None:       # read failed (already in failures)
-                continue
+    with obs.span("query.fetch_units"):
+        cid = source.container_id
+        out = {}
+        missing = []
+        for e in entries:
+            got = unit_cache.get((cid, e["off"]))
+            if got is None:
+                missing.append(e)
+            else:
+                out[e["off"]] = got
+        n_hits = len(entries) - len(missing)
+        if missing:
+            obs.count("query.units_decoded", len(missing))
+            held = None
+            if obs.enabled():
+                held, dup = _decoding.enter([(cid, e["off"])
+                                             for e in missing])
+                obs.count("query.decode_dup", dup)
             try:
-                encode.check_unit_frame(frame, e)
-                uh, secs = encode.unpack(frame)
-                u_rec, v_rec = ex.decode_unit(uh, secs)
-            except encode.ContainerError as exc:
-                if failures is None:
-                    raise
-                failures.append((e, exc))
-                continue
-            val = (tuple(uh["box"]), u_rec, v_rec)
-            unit_cache.put((cid, e["off"]), val)
-            out[e["off"]] = val
+                frames = source.read_many(missing, failures=failures)
+                for e, frame in zip(missing, frames):
+                    key = (cid, e["off"])
+                    try:
+                        if frame is None:   # read failed (in failures)
+                            continue
+                        with obs.span("query.unpack"):
+                            encode.check_unit_frame(frame, e)
+                            uh, secs = encode.unpack(frame)
+                        u_rec, v_rec = ex.decode_unit(uh, secs)
+                        val = (tuple(uh["box"]), u_rec, v_rec)
+                        unit_cache.put(key, val)
+                        out[e["off"]] = val
+                    except encode.ContainerError as exc:
+                        if failures is None:
+                            raise
+                        failures.append((e, exc))
+                    finally:
+                        if held is not None:
+                            _decoding.leave(held, [key])
+            finally:
+                if held:
+                    _decoding.leave(held)
     return [out[e["off"]] for e in entries if e["off"] in out], n_hits
 
 
@@ -539,73 +590,77 @@ def decode_for_track(src, track_id: int, backend=None,
     """
     from ..core import pipeline as pipeline_mod
 
-    source, hdr, idx = load_track_index(src)
-    with obs.span("query.decode_for_track",
-                  track_id=int(track_id)) as sp, source:
-        idx._check(track_id)
-        T, H, W = hdr["shape"]
-        entries = _cover_entries(hdr, idx, track_id)
-        ex = pipeline_mod.executor_from_header(hdr, backend)
+    # the spans of one call share its qid (nested spans inherit it)
+    with obs.span("query.decode_for_track", track_id=int(track_id),
+                  qid=next(_QIDS)) as sp, contextlib.ExitStack() as stack:
+        with obs.span("query.open"):
+            source, hdr, idx = load_track_index(src)
+            stack.enter_context(source)
+            idx._check(track_id)
+            T, H, W = hdr["shape"]
+            entries = _cover_entries(hdr, idx, track_id)
+            ex = pipeline_mod.executor_from_header(hdr, backend)
         failures = [] if degraded else None
         decoded, n_hits = fetch_decoded_units(source, ex, entries,
                                               failures=failures)
-        patches_u, patches_v = [], []
-        for box, u_rec, v_rec in decoded:
-            ufp, vfp = fixedpoint.refix(u_rec, v_rec, hdr["scale"])
-            patches_u.append((box, ufp))
-            patches_v.append((box, vfp))
-        up = _PatchField((T, H, W), patches_u)
-        vp = _PatchField((T, H, W), patches_v)
+        with obs.span("query.rebuild"):
+            patches_u, patches_v = [], []
+            for box, u_rec, v_rec in decoded:
+                ufp, vfp = fixedpoint.refix(u_rec, v_rec, hdr["scale"])
+                patches_u.append((box, ufp))
+                patches_v.append((box, vfp))
+            up = _PatchField((T, H, W), patches_u)
+            vp = _PatchField((T, H, W), patches_v)
 
-        seg_fid, seg_cell = idx.track_segments(track_id)
-        n_dropped = 0
-        if failures:
-            keep = _segment_survivors(
-                seg_cell, [tuple(e["box"]) for e, _ in failures],
-                (T, H, W))
-            n_dropped = int(len(seg_fid) - keep.sum())
-            seg_fid = seg_fid[keep]
-        missing = [{"key": tuple(e["key"]), "box": tuple(e["box"]),
-                    "error": str(err)} for e, err in (failures or ())]
-        acct = dict(
-            units_read=len(entries) - len(missing),
-            units_total=len(hdr["units"]),
-            bytes_read=int(sum(e["len"] for e in entries)),
-            entries=entries,
-            range_reads=source.reads,
-            bytes_fetched=source.bytes_fetched,
-            cache_hits=n_hits,
-            missing_units=missing,
-            segments_dropped=n_dropped,
-        )
-        sp.set(units=len(entries), cache_hits=n_hits,
-               range_reads=source.reads,
-               bytes_fetched=source.bytes_fetched)
-        if len(seg_fid) == 0:
-            return TrackDecode(track=None, **acct)
-        node_fid = np.unique(seg_fid)
-        local_edges = np.searchsorted(node_fid, seg_fid).astype(np.int64)
-        pos = extraction.node_positions(node_fid, up, vp, (T, H, W))
-        types = classify_mod.classify_nodes(up, vp, pos,
-                                            spiral_tol=idx.spiral_tol)
-        if n_dropped == 0:
-            # single-component assembly through the same code path as
-            # full extraction, so ordering / loop detection can never
-            # diverge
-            (track,) = model.build_tracks(
-                pos, node_fid, types,
-                np.zeros(len(node_fid), dtype=np.int32), local_edges)
+            seg_fid, seg_cell = idx.track_segments(track_id)
+            n_dropped = 0
+            if failures:
+                keep = _segment_survivors(
+                    seg_cell, [tuple(e["box"]) for e, _ in failures],
+                    (T, H, W))
+                n_dropped = int(len(seg_fid) - keep.sum())
+                seg_fid = seg_fid[keep]
+            missing = [{"key": tuple(e["key"]), "box": tuple(e["box"]),
+                        "error": str(err)} for e, err in (failures or ())]
+            acct = dict(
+                units_read=len(entries) - len(missing),
+                units_total=len(hdr["units"]),
+                bytes_read=int(sum(e["len"] for e in entries)),
+                entries=entries,
+                range_reads=source.reads,
+                bytes_fetched=source.bytes_fetched,
+                cache_hits=n_hits,
+                missing_units=missing,
+                segments_dropped=n_dropped,
+            )
+            sp.set(units=len(entries), cache_hits=n_hits,
+                   range_reads=source.reads,
+                   bytes_fetched=source.bytes_fetched)
+            if len(seg_fid) == 0:
+                return TrackDecode(track=None, **acct)
+            node_fid = np.unique(seg_fid)
+            local_edges = np.searchsorted(node_fid, seg_fid).astype(np.int64)
+            pos = extraction.node_positions(node_fid, up, vp, (T, H, W))
+            types = classify_mod.classify_nodes(up, vp, pos,
+                                                spiral_tol=idx.spiral_tol)
+            if n_dropped == 0:
+                # single-component assembly through the same code path as
+                # full extraction, so ordering / loop detection can never
+                # diverge
+                (track,) = model.build_tracks(
+                    pos, node_fid, types,
+                    np.zeros(len(node_fid), dtype=np.int32), local_edges)
+                return TrackDecode(
+                    track=dataclasses.replace(track, track_id=track_id),
+                    **acct)
+            # dropped segments can split the survivors into several
+            # connected pieces; label them and assemble each one
+            labels = np.asarray(backend_mod.connected_labels(
+                len(node_fid), local_edges, backend="numpy"))
+            track_of = extraction.dense_track_ids(node_fid, labels)
+            pieces = model.build_tracks(pos, node_fid, types,
+                                        track_of, local_edges)
+            pieces = tuple(sorted(pieces, key=lambda p: -len(p.face_ids)))
             return TrackDecode(
-                track=dataclasses.replace(track, track_id=track_id),
-                **acct)
-        # dropped segments can split the survivors into several
-        # connected pieces; label them and assemble each one
-        labels = np.asarray(backend_mod.connected_labels(
-            len(node_fid), local_edges, backend="numpy"))
-        track_of = extraction.dense_track_ids(node_fid, labels)
-        pieces = model.build_tracks(pos, node_fid, types,
-                                    track_of, local_edges)
-        pieces = tuple(sorted(pieces, key=lambda p: -len(p.face_ids)))
-        return TrackDecode(
-            track=dataclasses.replace(pieces[0], track_id=track_id),
-            pieces=pieces, **acct)
+                track=dataclasses.replace(pieces[0], track_id=track_id),
+                pieces=pieces, **acct)

@@ -936,20 +936,40 @@ class PlanExecutor:
             res_u, res_v, np.asarray(bm), p.scale, p.xi_unit, p.block,
             self.stepper)
 
+    def _sl_frames(self, bm) -> int:
+        """Frames of a payload with blockmap ``bm`` that step through
+        the SL stepper in ``decode_fields``."""
+        bm = np.asarray(bm)
+        if len(bm) < 2:
+            return 0                   # frame 0 is spatial-only
+        if self._impl["decode"] == "scan":
+            return len(bm) - 1
+        return int(bm[1:].reshape(len(bm) - 1, -1).any(axis=1).sum())
+
     def decode_payload(self, shape, sections):
         """sections -> reconstructed (u, v) float32 numpy arrays.  One
-        implementation for monolithic blobs and tiled container units."""
+        implementation for monolithic blobs and tiled container units.
+
+        ``pipeline.decode_sections`` times the host work (section parse,
+        lossless scatter), ``pipeline.decode_fields`` the device decode
+        up to the arrays on the host: the closing ``np.asarray`` waits
+        for the device, so that span needs no ``obs.device_sync``."""
         p = self.plan
-        res_u, res_v, bm, ll = encode.parse_field_sections(sections, shape)
-        xu, xv = self.decode_fields(res_u, res_v, bm)
-        u_raw = np.zeros(shape, dtype=np.float32)
-        v_raw = np.zeros(shape, dtype=np.float32)
-        u_raw[ll] = sections["u_ll"]
-        v_raw[ll] = sections["v_ll"]
-        u_rec, v_rec = _reconstruct(
-            xu, xv, p.scale, p.xi_unit,
-            jnp.asarray(ll), jnp.asarray(u_raw), jnp.asarray(v_raw))
-        return np.asarray(u_rec), np.asarray(v_rec)
+        with obs.span("pipeline.decode_sections"):
+            res_u, res_v, bm, ll = encode.parse_field_sections(sections,
+                                                               shape)
+            u_raw = np.zeros(shape, dtype=np.float32)
+            v_raw = np.zeros(shape, dtype=np.float32)
+            u_raw[ll] = sections["u_ll"]
+            v_raw[ll] = sections["v_ll"]
+        with obs.span("pipeline.decode_fields") as sp:
+            if obs.enabled():
+                sp.set(sl_frames=self._sl_frames(bm))
+            xu, xv = self.decode_fields(res_u, res_v, bm)
+            u_rec, v_rec = _reconstruct(
+                xu, xv, p.scale, p.xi_unit,
+                jnp.asarray(ll), jnp.asarray(u_raw), jnp.asarray(v_raw))
+            return np.asarray(u_rec), np.asarray(v_rec)
 
     def decode_unit(self, unit_header, sections):
         t0, t1, i0, i1, j0, j1 = unit_header["box"]

@@ -9,7 +9,7 @@ lock acquire + one integer op:
   ``ContainerSource.reads``) while every ``add`` also flows into the
   registry-wide parent, so one ``snapshot()`` sees process totals and
   per-object views stay exact.
-* ``Gauge`` -- last-write-wins scalar (queue depths, cache bytes).
+* ``Gauge`` -- last-write-wins scalar (a level, such as a queue depth).
 * ``Histogram`` -- fixed log2 buckets over non-negative integer
   observations (nanoseconds, bytes).  Bucket 0 counts exact zeros;
   bucket ``i >= 1`` counts values in ``[2^(i-1), 2^i)``; the last

@@ -17,6 +17,13 @@ import-time ``perf_counter_ns`` anchor, the unit Perfetto expects.
 The buffer is bounded (``MAX_EVENTS``); overflow drops new events and
 counts the drops, so a runaway trace degrades to missing tail data
 rather than unbounded memory.
+
+Every span is also mirrored into the JAX profiler as a
+``jax.profiler.TraceAnnotation`` of the same name, entered and left with
+it on the same thread: while a profiler trace is being taken, the spans
+appear on its host timeline beside the device operations, on the
+profiler's own clock.  A span nested in one that carries a request id
+(``qid``) carries the same id, so every span of one request shares it.
 """
 from __future__ import annotations
 
@@ -40,6 +47,10 @@ _TLS = threading.local()
 # to be thread-safe, and instrumentation must never raise.
 _EXIT_HOOK = None
 
+# jax.profiler.TraceAnnotation, imported on the first span (False when
+# JAX cannot be imported: spans then go unmirrored)
+_ANNOTATION = None
+
 
 def set_exit_hook(fn):
     """``fn(name, dur_ns)`` called after every Span exit (or None)."""
@@ -52,6 +63,25 @@ def _stack():
     if st is None:
         st = _TLS.stack = []
     return st
+
+
+def _annotation_enter(name: str):
+    """A profiler annotation for ``name``, entered, or None."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    if not _ANNOTATION:
+        return None
+    try:
+        ann = _ANNOTATION(name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None
 
 
 def _emit(ev):
@@ -70,26 +100,37 @@ class Span:
     their section timings from these instead of hand-rolled
     ``perf_counter`` pairs)."""
 
-    __slots__ = ("name", "args", "_t0", "dur_ns")
+    __slots__ = ("name", "args", "_t0", "dur_ns", "_ann")
 
     def __init__(self, name: str, args: dict | None = None):
         self.name = name
         self.args = dict(args) if args else {}
         self._t0 = 0
         self.dur_ns = 0
+        self._ann = None
 
     def set(self, **kw):
         self.args.update(kw)
         return self
 
     def __enter__(self):
-        _stack().append(self)
+        st = _stack()
+        if st and "qid" in st[-1].args:     # one request's spans share it
+            self.args.setdefault("qid", st[-1].args["qid"])
+        st.append(self)
+        self._ann = _annotation_enter(self.name)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
         self.dur_ns = t1 - self._t0
+        if self._ann is not None:
+            try:
+                self._ann.__exit__(None, None, None)
+            except Exception:
+                pass  # instrumentation must never take down the pipeline
+            self._ann = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -148,7 +189,7 @@ def current_span():
 
 
 def counter_event(name: str, **values):
-    """Sampled series (queue depth, cache bytes) as a Chrome counter
+    """Sampled series (queue depths) as a Chrome counter
     event; each keyword becomes one series under the counter track."""
     _emit({
         "name": name,

@@ -9,7 +9,8 @@ produce:
   * a valid Chrome-trace JSON (loads as ``{"traceEvents": [...]}``,
     Perfetto-compatible) containing spans for all three engine stages
     on distinct threads, with queue-depth counter events for both
-    handoff queues;
+    handoff queues, and the track query's stage spans (open, fetch,
+    unpack, decode sections, decode fields, rebuild) sharing its qid;
   * a registry snapshot covering pipeline, engine, journal, cache and
     retry metrics;
   * a container byte-identical to an obs-off run of the same input.
@@ -130,6 +131,17 @@ def main() -> int:
              "engine threads did not self-label")
         need(by_name.get("query.decode_for_track"),
              "no query.decode_for_track span")
+        # the cold query's stages, sharing its qid
+        qids = {e["args"].get("qid")
+                for e in by_name.get("query.decode_for_track", ())}
+        for stage in ("query.open", "query.fetch_units", "query.unpack",
+                      "pipeline.decode_sections", "pipeline.decode_fields",
+                      "query.rebuild"):
+            spans = by_name.get(stage, ())
+            need(spans, f"no {stage} span under the track query")
+            need(all(e["args"].get("qid") in qids for e in spans
+                     if e["name"].startswith("query.")),
+                 f"{stage} spans do not carry their query's qid")
 
         # ---- registry snapshot: all five metric families ----
         snap = obs.snapshot()
@@ -137,6 +149,7 @@ def main() -> int:
                      "engine.units_written", "journal.fsync",
                      "journal.checkpoints", "cache.hits", "cache.misses",
                      "query.range_reads", "query.bytes_fetched",
+                     "query.units_decoded", "query.decode_dup",
                      "faults.retry.source.read.attempts",
                      "faults.retry.source.read.retries"):
             need(name in snap, f"snapshot missing {name}")

@@ -274,3 +274,169 @@ def test_lorenzo_predictor_roundtrip():
         res = analysis.decode_for_track(blob, k)
         assert np.array_equal(res.track.nodes, full.track(k).nodes)
         assert res.units_read < res.units_total
+
+
+# ----------------------------------------------------------------------
+# read-path spans and the duplicate-decode counter
+# ----------------------------------------------------------------------
+
+READ_PATH_STAGES = ("query.open", "query.fetch_units", "query.unpack",
+                    "pipeline.decode_sections", "pipeline.decode_fields",
+                    "query.rebuild")
+
+
+@pytest.fixture
+def traced():
+    """Observability on for one test, restored (and the trace buffer
+    cleared) afterwards."""
+    from repro import obs
+    from repro.obs import trace
+
+    was = obs.enabled()
+    obs.enable()
+    trace.reset()
+    yield obs
+    (obs.enable if was else obs.disable)()
+    trace.reset()
+
+
+def _self_ns(span, spans):
+    """Duration of ``span`` less the union of the spans nested in it on
+    its thread."""
+    s, e = span["ts"], span["ts"] + span["dur"]
+    kids = sorted((k["ts"], k["ts"] + k["dur"]) for k in spans
+                  if k is not span and k["tid"] == span["tid"]
+                  and k["ts"] >= s and k["ts"] + k["dur"] <= e)
+    covered, reach = 0.0, s
+    for ks, ke in kids:
+        ks = max(ks, reach)
+        if ke > ks:
+            covered += ke - ks
+            reach = ke
+    return span["dur"] - covered
+
+
+def test_decode_for_track_stage_spans(indexed, traced):
+    """A cold track query yields the six stage spans, nested in
+    ``query.decode_for_track`` on its thread and sharing its qid; the
+    self times of the stages and of the parent add up to the parent's
+    duration."""
+    from repro.analysis import query as query_mod
+
+    _, _, blob, _ = indexed
+    query_mod.unit_cache.clear()
+    res = analysis.decode_for_track(blob, 0)
+    assert res.cache_hits == 0 and res.units_read > 0
+    spans = [e for e in traced.trace_events() if e["ph"] == "X"]
+    (parent,) = [e for e in spans if e["name"] == "query.decode_for_track"]
+    qid = parent["args"]["qid"]
+    kids = [e for e in spans if e["name"] in READ_PATH_STAGES]
+    assert {e["name"] for e in kids} == set(READ_PATH_STAGES)
+    n_units = res.units_read
+    for name, n in [("query.open", 1), ("query.fetch_units", 1),
+                    ("query.rebuild", 1), ("query.unpack", n_units),
+                    ("pipeline.decode_sections", n_units),
+                    ("pipeline.decode_fields", n_units)]:
+        assert sum(e["name"] == name for e in kids) == n, name
+    end = parent["ts"] + parent["dur"]
+    for e in kids:
+        assert e["tid"] == parent["tid"]
+        assert e["args"]["qid"] == qid
+        assert parent["ts"] <= e["ts"] and e["ts"] + e["dur"] <= end + 1e-3
+        assert "stack_corrupt" not in e["args"]
+    for e in kids:
+        if e["name"] == "pipeline.decode_fields":
+            assert e["args"]["sl_frames"] >= 0
+    total = sum(_self_ns(e, spans) for e in kids + [parent])
+    assert total == pytest.approx(parent["dur"], abs=1e-3 * len(kids))
+
+    # a second query takes the next qid
+    analysis.decode_for_track(blob, 0)
+    qids = [e["args"]["qid"] for e in traced.trace_events()
+            if e["name"] == "query.decode_for_track"]
+    assert len(set(qids)) == 2
+
+
+def _held_decode(monkeypatch):
+    """Make every unit decode wait, once it has started, until the
+    returned ``release`` event is set; ``arrived(n)`` waits until n
+    decodes have started."""
+    import threading
+
+    from repro.core import pipeline
+
+    cond = threading.Condition()
+    started = [0]
+    release = threading.Event()
+    orig = pipeline.PlanExecutor.decode_unit
+
+    def decode_unit(self, unit_header, sections):
+        with cond:
+            started[0] += 1
+            cond.notify_all()
+        assert release.wait(60)
+        return orig(self, unit_header, sections)
+
+    def arrived(n):
+        with cond:
+            return cond.wait_for(lambda: started[0] >= n, timeout=60)
+
+    monkeypatch.setattr(pipeline.PlanExecutor, "decode_unit", decode_unit)
+    return arrived, release
+
+
+@pytest.mark.parametrize("obs_on", [True, False])
+def test_concurrent_miss_counts_duplicate_decode(indexed, monkeypatch,
+                                                  obs_on):
+    """Two threads that miss the same uncached unit while one decode of
+    it is under way: with tracing on ``query.decode_dup`` rises by one,
+    with it off the in-flight set stays empty; both answers are right
+    either way."""
+    import threading
+
+    from repro import obs
+    from repro.analysis import query as query_mod
+    from repro.core import pipeline
+
+    _, _, blob, _ = indexed
+    k = 0
+    query_mod.unit_cache.clear()
+    ref = analysis.decode_for_track(blob, k)
+    # every covering unit but one in the cache: each query misses one
+    query_mod.unit_cache.clear()
+    source, hdr, _ = query_mod.load_track_index(blob)
+    with source:
+        query_mod.fetch_decoded_units(
+            source, pipeline.executor_from_header(hdr), ref.entries[1:])
+
+    was = obs.enabled()
+    (obs.enable if obs_on else obs.disable)()
+    try:
+        dup0 = obs.counter("query.decode_dup").value
+        arrived, release = _held_decode(monkeypatch)
+        answers = [None, None]
+
+        def ask(i):
+            answers[i] = analysis.decode_for_track(blob, k)
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(2)]
+        threads[0].start()
+        assert arrived(1)
+        threads[1].start()
+        assert arrived(2)
+        held = len(query_mod._decoding)
+        release.set()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        dup = obs.counter("query.decode_dup").value - dup0
+    finally:
+        (obs.enable if was else obs.disable)()
+    assert (held, dup) == ((1, 1) if obs_on else (0, 0))
+    assert len(query_mod._decoding) == 0
+    for res in answers:
+        assert res.cache_hits == len(ref.entries) - 1
+        assert np.array_equal(res.track.face_ids, ref.track.face_ids)
+        assert np.array_equal(res.track.nodes, ref.track.nodes)
+        assert np.array_equal(res.track.types, ref.track.types)
