@@ -23,7 +23,6 @@ would produce, node for node, bit for bit.
 """
 from __future__ import annotations
 
-import collections
 import contextlib
 import dataclasses
 import hashlib
@@ -315,41 +314,111 @@ def configure_unit_cache(max_mb: float) -> UnitCache:
     return unit_cache
 
 
-class _Decoding:
+class _Flight:
+    """One unit decode under way: ``done`` is set once the leading
+    thread has published ``value`` (the decoded patch) or ``error``
+    (the ContainerError it hit); both None means the leader gave up on
+    the unit for another reason."""
+
+    __slots__ = ("done", "value", "error")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.value = None
+        self.error = None
+
+
+class _InFlight:
     """The units that threads are decoding at this moment, as
-    ``(container_id, off)`` -> number of threads.  A cache miss on a
-    unit that another thread holds here is a duplicate decode
-    (``query.decode_dup``).  It only observes: both decodes still run.
-    Entered only while tracing is on."""
+    ``(container_id, off)`` -> :class:`_Flight`.  A cache miss on a key
+    held here waits for that decode instead of repeating it
+    (single-flight); tracing on or off makes no difference."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._n = collections.Counter()
+        self._flights = {}
 
     def __len__(self) -> int:
         with self._lock:
-            return len(self._n)
+            return len(self._flights)
 
-    def enter(self, keys: list) -> tuple:
-        """Hold ``keys``: (the set held, how many of them another thread
-        already holds)."""
+    def claim(self, cid, entries: list) -> tuple:
+        """Sort ``entries`` into cache hits ``{off: patch}``, units this
+        thread now leads and units another thread leads, the last two
+        as ``[(entry, key, flight)]``.  The cache is looked up under the
+        registry's lock, and a leader caches its patch before it lets go
+        of the key, so a miss never starts a decode that has just
+        finished."""
+        hits, led, joined = {}, [], []
         with self._lock:
-            dup = sum(1 for k in keys if self._n[k])
-            self._n.update(keys)
-        return set(keys), dup
+            for e in entries:
+                key = (cid, e["off"])
+                got = unit_cache.get(key)
+                if got is not None:
+                    hits[e["off"]] = got
+                elif key in self._flights:
+                    joined.append((e, key, self._flights[key]))
+                else:
+                    fl = self._flights[key] = _Flight()
+                    led.append((e, key, fl))
+        return hits, led, joined
 
-    def leave(self, held: set, keys=None):
-        """Let go of ``keys`` (default: everything still ``held``)."""
-        keys = set(held) if keys is None else held & set(keys)
+    def holds(self, key, flight) -> bool:
         with self._lock:
-            for k in keys:
-                held.discard(k)
-                self._n[k] -= 1
-                if self._n[k] <= 0:
-                    del self._n[k]
+            return self._flights.get(key) is flight
+
+    def finish(self, key, flight, value=None, error=None):
+        """Publish the leader's outcome, let go of the key and wake the
+        threads waiting on it."""
+        flight.value, flight.error = value, error
+        with self._lock:
+            if self._flights.get(key) is flight:
+                del self._flights[key]
+        flight.done.set()
 
 
-_decoding = _Decoding()
+_inflight = _InFlight()
+
+
+def _lead(source: ContainerSource, ex, led: list, out: dict,
+          failures: list):
+    """Range-read, CRC-check, unpack and decode the ``led`` units,
+    cache each patch and publish it to the threads waiting on it.
+    Every flight is finished on the way out, however this ends."""
+    pending = {key: fl for _, key, fl in led}
+    try:
+        unread = []
+        frames = source.read_many([e for e, _, _ in led], failures=unread)
+        unread = {id(e): exc for e, exc in unread}
+        for (e, key, fl), frame in zip(led, frames):
+            try:
+                if frame is None:   # the range read failed
+                    raise unread[id(e)]
+                with obs.span("query.unpack"):
+                    encode.check_unit_frame(frame, e)
+                    uh, secs = encode.unpack(frame)
+                # decode_dup: a decode begun on a key another thread
+                # holds; single-flight keeps it at 0
+                obs.count("query.units_decoded")
+                obs.count("query.decode_dup",
+                          int(not _inflight.holds(key, fl)))
+                u_rec, v_rec = ex.decode_unit(uh, secs)
+                val = (tuple(uh["box"]), u_rec, v_rec)
+                unit_cache.put(key, val)
+                out[e["off"]] = val
+                _inflight.finish(key, pending.pop(key), value=val)
+            except (encode.ContainerError, OSError) as exc:
+                # a corrupt frame is corrupt for every reader; a
+                # transient fault is this thread's alone (joiners redo)
+                corrupt = isinstance(exc, encode.ContainerError)
+                _inflight.finish(key, pending.pop(key),
+                                 error=exc if corrupt else None)
+                if failures is None:
+                    raise
+                failures.append((e, exc))
+    finally:
+        for key, fl in pending.items():
+            _inflight.finish(key, fl)
 
 
 def fetch_decoded_units(source: ContainerSource, ex, entries: list,
@@ -360,6 +429,13 @@ def fetch_decoded_units(source: ContainerSource, ex, entries: list,
     executor, and cached.  Returns (patches in entry order, cache hit
     count).
 
+    Single-flight: a miss on a unit another thread is decoding waits
+    for that decode (span ``query.decode_wait``) and takes its patch
+    (``query.decode_joined``) -- after this thread has decoded the
+    units it leads, so two threads waiting on each other's units
+    cannot deadlock.  A leader's ContainerError is the joiner's too; on
+    any other failure of the leader the joiner decodes the unit itself.
+
     With ``failures`` given (degraded mode), units that fail the range
     read, the CRC check, or decode are appended as ``(entry, exc)`` and
     SKIPPED -- the patch list then holds only the surviving units, in
@@ -367,45 +443,34 @@ def fetch_decoded_units(source: ContainerSource, ex, entries: list,
     with obs.span("query.fetch_units"):
         cid = source.container_id
         out = {}
-        missing = []
-        for e in entries:
-            got = unit_cache.get((cid, e["off"]))
-            if got is None:
-                missing.append(e)
-            else:
-                out[e["off"]] = got
-        n_hits = len(entries) - len(missing)
-        if missing:
-            obs.count("query.units_decoded", len(missing))
-            held = None
-            if obs.enabled():
-                held, dup = _decoding.enter([(cid, e["off"])
-                                             for e in missing])
-                obs.count("query.decode_dup", dup)
-            try:
-                frames = source.read_many(missing, failures=failures)
-                for e, frame in zip(missing, frames):
-                    key = (cid, e["off"])
-                    try:
-                        if frame is None:   # read failed (in failures)
+        hits, led, joined = _inflight.claim(cid, entries)
+        n_hits = len(hits)
+        while True:
+            out.update(hits)
+            if not led and not joined:
+                break
+            if led:
+                _lead(source, ex, led, out, failures)
+            redo = []
+            n_joined = 0
+            if joined:
+                with obs.span("query.decode_wait"):
+                    for e, _, fl in joined:
+                        fl.done.wait()
+                        if fl.value is not None:
+                            out[e["off"]] = fl.value
+                        elif fl.error is None:
+                            redo.append(e)   # the leader gave up on it
                             continue
-                        with obs.span("query.unpack"):
-                            encode.check_unit_frame(frame, e)
-                            uh, secs = encode.unpack(frame)
-                        u_rec, v_rec = ex.decode_unit(uh, secs)
-                        val = (tuple(uh["box"]), u_rec, v_rec)
-                        unit_cache.put(key, val)
-                        out[e["off"]] = val
-                    except encode.ContainerError as exc:
-                        if failures is None:
-                            raise
-                        failures.append((e, exc))
-                    finally:
-                        if held is not None:
-                            _decoding.leave(held, [key])
-            finally:
-                if held:
-                    _decoding.leave(held)
+                        elif failures is None:
+                            raise fl.error
+                        else:
+                            failures.append((e, fl.error))
+                        n_joined += 1
+            obs.count("query.decode_joined", n_joined)
+            if not redo:
+                break
+            hits, led, joined = _inflight.claim(cid, redo)
     return [out[e["off"]] for e in entries if e["off"] in out], n_hits
 
 

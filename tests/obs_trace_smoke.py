@@ -150,6 +150,7 @@ def main() -> int:
                      "journal.checkpoints", "cache.hits", "cache.misses",
                      "query.range_reads", "query.bytes_fetched",
                      "query.units_decoded", "query.decode_dup",
+                     "query.decode_joined",
                      "faults.retry.source.read.attempts",
                      "faults.retry.source.read.retries"):
             need(name in snap, f"snapshot missing {name}")

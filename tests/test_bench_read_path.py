@@ -26,7 +26,8 @@ READ_PATH_METRICS = [
     "stage_ms_per_query.open", "stage_ms_per_query.fetch",
     "stage_ms_per_query.unpack", "stage_ms_per_query.sections",
     "stage_ms_per_query.device_decode", "stage_ms_per_query.rebuild",
-    "device_decode_concurrency", "duplicate_decode_share"]
+    "device_decode_concurrency", "duplicate_decode_share",
+    "decode_joined_share"]
 
 
 @pytest.fixture
@@ -127,4 +128,5 @@ def test_traced_query_rehearsal_reports_read_path_metrics(
         assert got[m] >= 0
     assert got["stage_ms_per_query.device_decode"] > 0
     assert got["device_decode_concurrency"] >= 1.0
-    assert 0 <= got["duplicate_decode_share"] <= 100
+    assert got["duplicate_decode_share"] == 0     # single-flight decode
+    assert 0 <= got["decode_joined_share"] <= 100

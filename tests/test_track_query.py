@@ -357,10 +357,11 @@ def test_decode_for_track_stage_spans(indexed, traced):
     assert len(set(qids)) == 2
 
 
-def _held_decode(monkeypatch):
+def _held_decode(monkeypatch, fail=None):
     """Make every unit decode wait, once it has started, until the
     returned ``release`` event is set; ``arrived(n)`` waits until n
-    decodes have started."""
+    decodes have started, and ``started[0]`` counts them.  With
+    ``fail`` given, the first decode raises it once released."""
     import threading
 
     from repro.core import pipeline
@@ -373,8 +374,11 @@ def _held_decode(monkeypatch):
     def decode_unit(self, unit_header, sections):
         with cond:
             started[0] += 1
+            first = started[0] == 1
             cond.notify_all()
         assert release.wait(60)
+        if fail is not None and first:
+            raise fail
         return orig(self, unit_header, sections)
 
     def arrived(n):
@@ -382,61 +386,264 @@ def _held_decode(monkeypatch):
             return cond.wait_for(lambda: started[0] >= n, timeout=60)
 
     monkeypatch.setattr(pipeline.PlanExecutor, "decode_unit", decode_unit)
-    return arrived, release
+    return arrived, release, started
 
 
-@pytest.mark.parametrize("obs_on", [True, False])
-def test_concurrent_miss_counts_duplicate_decode(indexed, monkeypatch,
-                                                  obs_on):
-    """Two threads that miss the same uncached unit while one decode of
-    it is under way: with tracing on ``query.decode_dup`` rises by one,
-    with it off the in-flight set stays empty; both answers are right
-    either way."""
+def _claims(monkeypatch):
+    """Record what each registry claim returns, as (led, joined) counts;
+    ``claimed(n)`` waits until n claims have returned."""
     import threading
 
-    from repro import obs
+    from repro.analysis import query as query_mod
+
+    cond = threading.Condition()
+    got = []
+    orig = query_mod._InFlight.claim
+
+    def claim(self, cid, entries):
+        hits, led, joined = orig(self, cid, entries)
+        with cond:
+            got.append((len(led), len(joined)))
+            cond.notify_all()
+        return hits, led, joined
+
+    def claimed(n):
+        with cond:
+            return cond.wait_for(lambda: len(got) >= n, timeout=60)
+
+    monkeypatch.setattr(query_mod._InFlight, "claim", claim)
+    return claimed, got
+
+
+def _one_unit_missing(blob, k):
+    """Reference decode of track ``k``, then every covering unit but the
+    first put in the cache: each query of ``k`` misses one unit."""
     from repro.analysis import query as query_mod
     from repro.core import pipeline
 
-    _, _, blob, _ = indexed
-    k = 0
     query_mod.unit_cache.clear()
     ref = analysis.decode_for_track(blob, k)
-    # every covering unit but one in the cache: each query misses one
     query_mod.unit_cache.clear()
     source, hdr, _ = query_mod.load_track_index(blob)
     with source:
         query_mod.fetch_decoded_units(
             source, pipeline.executor_from_header(hdr), ref.entries[1:])
+    return ref
+
+
+def _same_track(res, ref):
+    assert np.array_equal(res.track.face_ids, ref.track.face_ids)
+    assert np.array_equal(res.track.nodes, ref.track.nodes)
+    assert np.array_equal(res.track.types, ref.track.types)
+
+
+def _race(first, second, arrived, claimed, release):
+    """Run ``first`` on a thread until its first unit decode is under
+    way, then ``second`` on another until it has claimed its units, then
+    let the decode go on.  Returns the two results (or the exceptions
+    raised) and the number of units in flight at that moment."""
+    import threading
+
+    from repro.analysis import query as query_mod
+
+    out = [None, None]
+
+    def run(i, fn):
+        try:
+            out[i] = fn()
+        except Exception as exc:   # noqa: BLE001 -- reported to the test
+            out[i] = exc
+
+    threads = [threading.Thread(target=run, args=(i, fn))
+               for i, fn in enumerate((first, second))]
+    threads[0].start()
+    assert arrived(1)
+    threads[1].start()
+    assert claimed(2)
+    in_flight = len(query_mod._inflight)
+    release.set()
+    for t in threads:
+        t.join(60)
+        assert not t.is_alive()
+    return out, in_flight
+
+
+@pytest.mark.parametrize("obs_on", [True, False])
+def test_concurrent_miss_decodes_once(indexed, monkeypatch, obs_on):
+    """Two threads that miss the same uncached unit while one decode of
+    it is under way: the second waits for that decode and takes its
+    patch, tracing on or off.  The unit decodes once; with tracing on
+    ``query.decode_joined`` rises by one and ``query.decode_dup`` stays;
+    both answers are bit-identical to the reference."""
+    from repro import obs
+    from repro.analysis import query as query_mod
+
+    _, _, blob, _ = indexed
+    k = 0
+    ref = _one_unit_missing(blob, k)
 
     was = obs.enabled()
     (obs.enable if obs_on else obs.disable)()
     try:
         dup0 = obs.counter("query.decode_dup").value
-        arrived, release = _held_decode(monkeypatch)
-        answers = [None, None]
-
-        def ask(i):
-            answers[i] = analysis.decode_for_track(blob, k)
-
-        threads = [threading.Thread(target=ask, args=(i,))
-                   for i in range(2)]
-        threads[0].start()
-        assert arrived(1)
-        threads[1].start()
-        assert arrived(2)
-        held = len(query_mod._decoding)
-        release.set()
-        for t in threads:
-            t.join(60)
-            assert not t.is_alive()
+        joined0 = obs.counter("query.decode_joined").value
+        arrived, release, started = _held_decode(monkeypatch)
+        claimed, claims = _claims(monkeypatch)
+        answers, held = _race(lambda: analysis.decode_for_track(blob, k),
+                              lambda: analysis.decode_for_track(blob, k),
+                              arrived, claimed, release)
         dup = obs.counter("query.decode_dup").value - dup0
+        joined = obs.counter("query.decode_joined").value - joined0
     finally:
         (obs.enable if was else obs.disable)()
-    assert (held, dup) == ((1, 1) if obs_on else (0, 0))
-    assert len(query_mod._decoding) == 0
+    assert started[0] == 1
+    assert claims == [(1, 0), (0, 1)]
+    assert held == 1
+    assert (joined, dup) == ((1, 0) if obs_on else (0, 0))
+    assert len(query_mod._inflight) == 0
     for res in answers:
         assert res.cache_hits == len(ref.entries) - 1
-        assert np.array_equal(res.track.face_ids, ref.track.face_ids)
-        assert np.array_equal(res.track.nodes, ref.track.nodes)
-        assert np.array_equal(res.track.types, ref.track.types)
+        _same_track(res, ref)
+
+
+@pytest.mark.parametrize("degraded", [False, True])
+def test_joiner_shares_leaders_container_error(indexed, monkeypatch,
+                                               degraded):
+    """A leader whose decode finds the unit corrupt: the thread that
+    joined it gets the same ContainerError -- raised in strict mode,
+    the unit reported missing in degraded mode -- without a decode of
+    its own."""
+    from repro.analysis import query as query_mod
+
+    _, _, blob, _ = indexed
+    k = 0
+    ref = _one_unit_missing(blob, k)
+    bad = encode.ContainerError("planted corrupt unit")
+    arrived, release, started = _held_decode(monkeypatch, fail=bad)
+    claimed, _ = _claims(monkeypatch)
+
+    def ask():
+        return analysis.decode_for_track(blob, k, degraded=degraded)
+
+    answers, _ = _race(ask, ask, arrived, claimed, release)
+    assert started[0] == 1
+    assert len(query_mod._inflight) == 0
+    for res in answers:
+        if not degraded:
+            assert res is bad
+            continue
+        assert [m["key"] for m in res.missing_units] \
+            == [tuple(ref.entries[0]["key"])]
+        assert res.missing_units[0]["error"] == str(bad)
+
+
+def test_joiner_redoes_after_leaders_transient_fault(indexed,
+                                                     monkeypatch):
+    """A leader that fails with an OSError (a transient fault its
+    retries gave up on): the joiner does not inherit it, but decodes
+    the unit itself and answers correctly."""
+    from repro.analysis import query as query_mod
+
+    _, _, blob, _ = indexed
+    k = 0
+    ref = _one_unit_missing(blob, k)
+    fault = OSError("planted transient fault")
+    arrived, release, started = _held_decode(monkeypatch, fail=fault)
+    claimed, claims = _claims(monkeypatch)
+    answers, _ = _race(lambda: analysis.decode_for_track(blob, k),
+                       lambda: analysis.decode_for_track(blob, k),
+                       arrived, claimed, release)
+    assert answers[0] is fault
+    assert claims == [(1, 0), (0, 1), (1, 0)]    # the joiner led a redo
+    assert started[0] == 2
+    assert len(query_mod._inflight) == 0
+    assert answers[1].cache_hits == len(ref.entries) - 1
+    _same_track(answers[1], ref)
+
+
+def test_opposite_order_misses_finish(indexed, monkeypatch):
+    """Two threads that need the same two uncached units, in opposite
+    order, while the first is decoding: both finish, with two decodes
+    in all.  A thread claims its units at once and decodes the ones it
+    leads before it waits on any other, so no two threads wait on each
+    other."""
+    from repro.analysis import query as query_mod
+    from repro.core import pipeline
+
+    _, _, blob, _ = indexed
+    query_mod.unit_cache.clear()
+    source, hdr, _ = query_mod.load_track_index(blob)
+    ex = pipeline.executor_from_header(hdr)
+    a, b = hdr["units"][:2]
+    arrived, release, started = _held_decode(monkeypatch)
+    claimed, claims = _claims(monkeypatch)
+    with source:
+        got, _ = _race(
+            lambda: query_mod.fetch_decoded_units(source, ex, [a, b]),
+            lambda: query_mod.fetch_decoded_units(source, ex, [b, a]),
+            arrived, claimed, release)
+    assert started[0] == 2
+    assert claims == [(2, 0), (0, 2)]
+    assert len(query_mod._inflight) == 0
+    (pa, pb), hits0 = got[0]
+    (qb, qa), hits1 = got[1]
+    assert hits0 == hits1 == 0
+    assert pa is qa and pb is qb          # the very same patches
+    assert pa[0] == tuple(a["box"]) and pb[0] == tuple(b["box"])
+
+
+def test_concurrent_miss_decodes_once_uncached(indexed, monkeypatch):
+    """With the cache disabled the joiner still takes the leader's
+    patches, from the in-flight record: every covering unit decodes
+    once."""
+    from repro.analysis import query as query_mod
+
+    _, _, blob, _ = indexed
+    k = 0
+    query_mod.unit_cache.clear()
+    ref = analysis.decode_for_track(blob, k)
+    cache = query_mod.configure_unit_cache(0)
+    try:
+        arrived, release, started = _held_decode(monkeypatch)
+        claimed, claims = _claims(monkeypatch)
+        answers, _ = _race(lambda: analysis.decode_for_track(blob, k),
+                           lambda: analysis.decode_for_track(blob, k),
+                           arrived, claimed, release)
+        assert cache.stats()["entries"] == 0
+    finally:
+        query_mod.configure_unit_cache(256)
+    n = len(ref.entries)
+    assert started[0] == n
+    assert claims == [(n, 0), (0, n)]
+    assert len(query_mod._inflight) == 0
+    for res in answers:
+        assert res.cache_hits == 0
+        _same_track(res, ref)
+
+
+def test_region_decode_and_track_query_share_decode(indexed, monkeypatch):
+    """A region decode over a track's uncached covering unit and a
+    query of that track at the same moment decode the unit once; both
+    answers are right."""
+    from repro.analysis import query as query_mod
+    from repro.core import decompress_region
+
+    _, _, blob, _ = indexed
+    k = 0
+    ref = _one_unit_missing(blob, k)
+    box = tuple(ref.entries[0]["box"])
+    ur, vr = decompress_tiled(blob)
+    want = [x[box[0]:box[1], box[2]:box[3], box[4]:box[5]]
+            for x in (ur, vr)]
+    arrived, release, started = _held_decode(monkeypatch)
+    claimed, claims = _claims(monkeypatch)
+    (region, res), _ = _race(lambda: decompress_region(blob, box),
+                             lambda: analysis.decode_for_track(blob, k),
+                             arrived, claimed, release)
+    assert started[0] == 1
+    assert claims == [(1, 0), (0, 1)]
+    assert len(query_mod._inflight) == 0
+    assert np.array_equal(region[0], want[0])
+    assert np.array_equal(region[1], want[1])
+    assert res.cache_hits == len(ref.entries) - 1
+    _same_track(res, ref)
