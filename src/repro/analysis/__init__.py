@@ -12,6 +12,9 @@ queryable objects:
 * ``decode_for_track``    -- decode ONLY the covering units and rebuild
                              the exact polyline
 * ``track_summaries``     -- all per-track index summaries
+* ``configure_read_devices`` / ``warm_read_path``
+                          -- the devices unit decodes run on, and
+                             building every decode on each of them
 * ``track_aware_policy``  -- tighten-near-trajectories adaptive eb
                              policy (core.ebpolicy; DESIGN.md #16)
 
@@ -32,6 +35,7 @@ from .query import (  # noqa: F401
     ContainerSource,
     TrackDecode,
     UnitCache,
+    configure_read_devices,
     configure_unit_cache,
     decode_for_track,
     load_track_index,
@@ -39,4 +43,5 @@ from .query import (  # noqa: F401
     track_read_plan,
     track_summaries,
     unit_cache,
+    warm_read_path,
 )

@@ -380,6 +380,82 @@ class _InFlight:
 _inflight = _InFlight()
 
 
+class _ReadDevices:
+    """The devices the read path decodes units on, and how many unit
+    decodes each has under way.  ``None`` in the set stands for the
+    default device (the pipeline then places nothing)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.configure(None)
+
+    def configure(self, devices) -> list:
+        with self._lock:
+            self.devices = list(devices) if devices else [None]
+            self._busy = [0] * len(self.devices)
+            return list(self.devices)
+
+    @contextlib.contextmanager
+    def least_loaded(self):
+        """``(index, device)`` of the device with the fewest decodes
+        under way (ties: the lowest index), counted busy until the
+        block exits."""
+        with self._lock:
+            busy, devices = self._busy, self.devices
+            i = busy.index(min(busy))
+            busy[i] += 1
+        try:
+            yield i, devices[i]
+        finally:
+            with self._lock:
+                busy[i] -= 1
+
+
+_read_devices = _ReadDevices()
+
+
+def configure_read_devices(devices=None) -> list:
+    """Set the devices the read path (track queries, region decode)
+    decodes units on: each unit a thread leads goes to the device with
+    the fewest unit decodes under way.  ``None`` or empty: the default
+    device only, as without this call.  Single-flight is unchanged: a
+    unit is decoded once whatever the device, and threads on any device
+    share its patch.  Returns the configured set."""
+    return _read_devices.configure(devices)
+
+
+def warm_read_path(src, backend=None) -> list:
+    """Decode every unit of the container once on every configured read
+    device, the devices side by side, then empty the decoded-unit cache:
+    every program a unit decode runs is then built on every device, as an
+    archive server does when it opens an archive.  Returns the directory
+    entries of the units whose decodes were not the same bytes on every
+    device (none, where the devices agree)."""
+    from ..core import pipeline as pipeline_mod
+    from ..parallel.sharding import host_map, host_pool
+
+    with ContainerSource(src) as source:
+        hdr = source.header()
+        units = [(e, source.unit(e)) for e in hdr["units"]]
+    ex = pipeline_mod.executor_from_header(hdr, backend)
+    devices = _read_devices.devices
+
+    def decode_all(i):
+        digests = []
+        for _, (uh, secs) in units:
+            u_rec, v_rec = ex.decode_unit(uh, secs, device=devices[i],
+                                          chip=i)
+            digests.append(hashlib.sha256(u_rec.tobytes()
+                                          + v_rec.tobytes()).digest())
+        return digests
+
+    per_device = host_map(host_pool("read-warm", len(devices)), decode_all,
+                          range(len(devices)))
+    unit_cache.clear()
+    return [e for k, (e, _) in enumerate(units)
+            if len({d[k] for d in per_device}) > 1]
+
+
 def _lead(source: ContainerSource, ex, led: list, out: dict,
           failures: list):
     """Range-read, CRC-check, unpack and decode the ``led`` units,
@@ -402,7 +478,10 @@ def _lead(source: ContainerSource, ex, led: list, out: dict,
                 obs.count("query.units_decoded")
                 obs.count("query.decode_dup",
                           int(not _inflight.holds(key, fl)))
-                u_rec, v_rec = ex.decode_unit(uh, secs)
+                with _read_devices.least_loaded() as (i, dev):
+                    obs.count(f"query.units_decoded.chip{i}")
+                    u_rec, v_rec = ex.decode_unit(uh, secs, device=dev,
+                                                  chip=i)
                 val = (tuple(uh["box"]), u_rec, v_rec)
                 unit_cache.put(key, val)
                 out[e["off"]] = val

@@ -53,6 +53,7 @@ signature and live for the process; ``clear_registries()`` resets them.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import threading
@@ -887,6 +888,16 @@ def check_faces(fns: UnitFns, shape, ufp_j, vfp_j, ur_fp, vr_fp, preds,
     return face_recheck(fns, shape, ur_fp, vr_fp, preds, selection)
 
 
+def _on_device(device):
+    """Make ``device`` the calling thread's default device (a no-op for
+    None), so that the arrays a decode makes inside (the SL frames'
+    masks) land beside the unit's committed arrays instead of being
+    copied over from the process's default device."""
+    if device is None:
+        return contextlib.nullcontext()
+    return jax.default_device(device)
+
+
 # ----------------------------------------------------------------------
 # executor
 # ----------------------------------------------------------------------
@@ -946,9 +957,16 @@ class PlanExecutor:
             return len(bm) - 1
         return int(bm[1:].reshape(len(bm) - 1, -1).any(axis=1).sum())
 
-    def decode_payload(self, shape, sections):
+    def decode_payload(self, shape, sections, device=None, chip=None):
         """sections -> reconstructed (u, v) float32 numpy arrays.  One
         implementation for monolithic blobs and tiled container units.
+
+        ``device``: the JAX device that runs the decode.  The unit's
+        arrays are committed to it and every program of the decode (SL
+        stepper, fusions, reconstruction) runs there; ``None`` leaves
+        everything on the default device.  ``chip`` is the device's
+        index in the caller's device set, recorded as the
+        ``pipeline.decode_fields`` span's ``device`` arg.
 
         ``pipeline.decode_sections`` times the host work (section parse,
         lossless scatter), ``pipeline.decode_fields`` the device decode
@@ -962,18 +980,25 @@ class PlanExecutor:
             v_raw = np.zeros(shape, dtype=np.float32)
             u_raw[ll] = sections["u_ll"]
             v_raw[ll] = sections["v_ll"]
-        with obs.span("pipeline.decode_fields") as sp:
+        with obs.span("pipeline.decode_fields") as sp, \
+                _on_device(device):
             if obs.enabled():
                 sp.set(sl_frames=self._sl_frames(bm))
+                if chip is not None:
+                    sp.set(device=chip)
+            if device is not None:
+                res_u, res_v, ll, u_raw, v_raw = jax.device_put(
+                    (res_u, res_v, ll, u_raw, v_raw), device)
             xu, xv = self.decode_fields(res_u, res_v, bm)
             u_rec, v_rec = _reconstruct(
                 xu, xv, p.scale, p.xi_unit,
                 jnp.asarray(ll), jnp.asarray(u_raw), jnp.asarray(v_raw))
             return np.asarray(u_rec), np.asarray(v_rec)
 
-    def decode_unit(self, unit_header, sections):
+    def decode_unit(self, unit_header, sections, device=None, chip=None):
         t0, t1, i0, i1, j0, j1 = unit_header["box"]
-        return self.decode_payload((t1 - t0, i1 - i0, j1 - j0), sections)
+        return self.decode_payload((t1 - t0, i1 - i0, j1 - j0), sections,
+                                   device=device, chip=chip)
 
     # ---- symbolize/pack stage (host codec vs device entropy stage) ------
 

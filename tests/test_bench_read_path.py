@@ -1,7 +1,7 @@
 """The benchmark's readings of the analyst read path: the program's
-spans placed on the profiler's clock, the span-overlap reader, and a
-traced CPU rehearsal of the track-query cell that reports every
-read-path metric."""
+spans placed on the profiler's clock, the span-overlap, busy-spread and
+counter-balance readers, and a traced CPU rehearsal of the track-query
+cell that reports every read-path metric."""
 import argparse
 import glob
 import os
@@ -17,7 +17,8 @@ if ROOT not in sys.path:
 
 from bench import harness, run  # noqa: E402
 from bench import trace as bench_trace  # noqa: E402
-from bench.readers import counter_ratio_kept, span_overlap  # noqa: E402
+from bench.readers import (  # noqa: E402
+    counter_balance, counter_ratio_kept, device_busy_spread, span_overlap)
 from bench.tests import tiny  # noqa: E402
 from repro import obs  # noqa: E402
 from repro.obs import trace as obs_trace  # noqa: E402
@@ -105,6 +106,45 @@ def test_counter_ratio_kept(kept, want):
         counter=lambda c: kept.get(c, {}).get("value", 0), counts={})
     got = counter_ratio_kept.read(r, num=["q.dup"], den=["q.dec"],
                                   scale=100.0)
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def _planes(*planes):
+    """Readings whose trace holds one device plane per list of (start,
+    end) operations, over a window [0, 100)."""
+    device = {f"/device:TPU:{i}": [("m/op", s, e, "") for s, e in ops]
+              for i, ops in enumerate(planes)}
+    return types.SimpleNamespace(trace=bench_trace.Reduced(
+        {"device": device, "host": []}, 0.0, 100.0))
+
+
+@pytest.mark.parametrize("planes, want", [
+    ([[(0, 50)], [(50, 100)]], 0.0),            # as busy as each other
+    ([[(0, 80)], [(10, 30), (20, 40)]], 50.0),  # 80% against 30%
+    ([[(-10, 20)], [(90, 120)], [(0, 5)]], 15.0),  # clipped to the window
+    ([[(0, 30)]], 0.0),                         # one chip
+])
+def test_device_busy_spread(planes, want):
+    assert device_busy_spread.read(_planes(*planes)) == pytest.approx(want)
+
+
+def test_device_busy_spread_reads_nothing_without_planes():
+    assert device_busy_spread.read(_planes()) is None
+
+
+@pytest.mark.parametrize("rises, want", [
+    ({"c0": 3, "c1": 3, "c2": 3, "c3": 3}, 1.0),   # even
+    ({"c0": 6, "c1": 2, "c2": 2, "c3": 2}, 2.0),
+    ({"c0": 5}, 4.0),                             # all on one chip
+    ({"c0": 0, "c1": 0}, None),                   # nothing decoded
+    ({}, None),                                   # an older program
+])
+def test_counter_balance(rises, want):
+    r = types.SimpleNamespace(
+        run=types.SimpleNamespace(counters1={
+            c: {"value": n} for c, n in rises.items()}),
+        counter=lambda c: rises.get(c, 0))
+    got = counter_balance.read(r, counters=["c0", "c1", "c2", "c3"])
     assert got == (None if want is None else pytest.approx(want))
 
 

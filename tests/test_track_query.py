@@ -371,7 +371,7 @@ def _held_decode(monkeypatch, fail=None):
     release = threading.Event()
     orig = pipeline.PlanExecutor.decode_unit
 
-    def decode_unit(self, unit_header, sections):
+    def decode_unit(self, unit_header, sections, **placement):
         with cond:
             started[0] += 1
             first = started[0] == 1
@@ -379,7 +379,7 @@ def _held_decode(monkeypatch, fail=None):
         assert release.wait(60)
         if fail is not None and first:
             raise fail
-        return orig(self, unit_header, sections)
+        return orig(self, unit_header, sections, **placement)
 
     def arrived(n):
         with cond:
